@@ -1,308 +1,52 @@
-"""JAX-version portability layer for the distributed runtime.
+"""The one seam between the distributed runtime and JAX's shard_map/vma surface.
 
 The collective-explicit programs in this repo are written against the
-"vma-typed" shard_map surface (JAX >= 0.6: ``jax.shard_map``, ``lax.pvary``,
-``jax.typeof(x).vma``).  The pinned runtime floor is JAX 0.4.37, where the
-same machinery exists under older names and an older typing discipline:
+vma-typed shard_map of the installed JAX (0.9): ``jax.shard_map`` with
+``check_vma=True``, ``lax.pcast(..., to="varying")`` (the former
+``lax.pvary``) and ``jax.typeof(x).vma``.  Everything
+under ``src/`` reaches those symbols through this module only, so a JAX
+upgrade touches exactly one file (``tools/check_compat.py`` enforces it).
 
-  new surface (0.6+)                 0.4.x equivalent
-  ---------------------------------  -----------------------------------------
-  jax.shard_map(..., check_vma=)     jax.experimental.shard_map.shard_map(...,
-                                     check_rep=)
-  lax.pvary(x, axes)                 no-op: the check_rep=True rewriter tracks
-                                     replication itself and inserts the
-                                     pbroadcasts (the predecessor of vma
-                                     typing), so values never need marking
-  jax.typeof(x).vma                  no vma typing -> empty set
-  lax.all_gather_invariant           lax.all_gather (0.4.x all_gather is
-                                     already replication-typed as "invariant":
-                                     out_rep = in_rep | {axis}, transpose
-                                     without a psum)
-  jax.make_mesh(..., axis_types=)    jax.make_mesh without axis_types (no
-                                     AxisType; everything is Auto)
-
-Everything under ``src/`` must reach these symbols through this module only —
-never ``jax.shard_map`` / ``lax.pvary`` / ``jax.typeof`` directly — so the
-repo runs unmodified across JAX 0.4.x -> 0.7.x (enforced by CI on both ends
-of the range).
+``out_struct`` is the piece the Pallas kernels need: inside a
+``check_vma=True`` shard_map a ``pallas_call`` output must state which mesh
+axes it varies over, and a kernel's output varies wherever its inputs do.
 """
 from __future__ import annotations
 
-import inspect
-from typing import Any
-
 import jax
-import jax.numpy as jnp  # noqa: F401  (re-export convenience)
 from jax import lax
+from jax._src.lax.parallel import all_gather_invariant as _agi
 
 __all__ = [
-    "JAX_VERSION", "HAS_VMA", "HAS_NATIVE_SHARD_MAP", "HAS_AXIS_TYPE",
     "shard_map", "pvary", "vma_of", "pvary_missing", "match_vma",
-    "all_gather_invariant", "make_mesh", "tree_map", "scan", "checkpoint",
-    "tp_entry_mark",
+    "out_struct", "all_gather_invariant", "make_mesh",
 ]
 
 
-def _version_tuple(v: str) -> tuple[int, ...]:
-    parts = []
-    for p in v.split(".")[:3]:
-        digits = "".join(ch for ch in p if ch.isdigit())
-        parts.append(int(digits) if digits else 0)
-    return tuple(parts)
-
-
-JAX_VERSION: tuple[int, ...] = _version_tuple(jax.__version__)
-
-# --- feature probes (capability-based, not version-number-based) ------------
-HAS_NATIVE_SHARD_MAP: bool = hasattr(jax, "shard_map")
-HAS_AXIS_TYPE: bool = hasattr(jax.sharding, "AxisType")
-
-if hasattr(lax, "pvary"):
-    def _pvary_impl(x, axes):
-        return lax.pvary(x, axes)
-elif hasattr(lax, "pcast"):  # short-lived intermediate spelling
-    def _pvary_impl(x, axes):
-        return lax.pcast(x, axes, to="varying")
-else:
-    _pvary_impl = None
-
-# vma typing needs both the marking op and the typed-aval query.
-HAS_VMA: bool = _pvary_impl is not None and hasattr(jax, "typeof")
-
-tree_map = jax.tree.map if hasattr(jax, "tree") else jax.tree_util.tree_map
-
-
-# ---------------------------------------------------------------------------
-# shard_map
-# ---------------------------------------------------------------------------
-if HAS_NATIVE_SHARD_MAP:
-    _shard_map_impl = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-_SM_PARAMS = frozenset(inspect.signature(_shard_map_impl).parameters)
-if "check_vma" in _SM_PARAMS:
-    _SM_CHECK_KW: str | None = "check_vma"
-elif "check_rep" in _SM_PARAMS:
-    _SM_CHECK_KW = "check_rep"
-else:
-    _SM_CHECK_KW = None
-
-
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """One stable spelling of shard_map across API generations.
-
-    ``check_vma`` maps onto 0.4.x ``check_rep`` *faithfully* (not disabled):
-    the check_rep=True rewriter is what gives psum & friends the correct
-    per-device transpose semantics on old JAX, exactly as vma typing does on
-    new JAX.  Call sites that pass ``check_vma=False`` (pure data movement,
-    no AD) get the check disabled on both generations.
-    """
-    kwargs: dict[str, Any] = {}
-    if _SM_CHECK_KW is not None:
-        kwargs[_SM_CHECK_KW] = check_vma
-    return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, **kwargs)
+    """``jax.shard_map``.  Call sites that pass ``check_vma=False`` are pure
+    data movement with no AD inside."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
-def _relax_pre_vma_rep_checker() -> None:
-    """Pre-vma JAX only: keep shard_map's efficient-transpose rewrite but
-    trust ``out_specs`` over the static replication checker.
-
-    On 0.4.x, ``check_rep=True`` couples two things: (a) the rewrite that
-    tracks replication and inserts ``pbroadcast``s so collectives get the
-    correct per-device transpose — the direct predecessor of vma typing, and
-    required for the in-shard_map AD these programs do — and (b) a
-    conservative static checker that must *prove* each out_spec's implied
-    replication.  The checker routinely fails on these collective-explicit
-    programs because the proof needs exactly the facts ``lax.pvary`` states
-    explicitly on vma JAX, and pvary is a no-op here.  Under-inferred
-    replication is harmless to the rewrite itself (a value tracked as varying
-    but physically replicated behaves identically — same psum results, same
-    transposes), so we neutralise only the prove-or-raise gates:
-    ``_valid_repeats`` (staging + jaxpr typecheck) and ``_check_reps2``
-    (rewrite output matching).  Outputs claimed replicated by an out_spec are
-    then trusted, which is precisely the contract the vma type system
-    enforces statically on newer JAX — and the suite verifies numerically
-    here.
-    """
-    import jax.experimental.shard_map as _smod
-
-    _smod._valid_repeats = lambda mesh, rep, dst: True
-    _smod._check_reps2 = lambda mesh, reps_dest, reps: None
-
-    # The per-primitive typing rules for the rewriter's own psum2/pbroadcast
-    # raise when applied to an unexpectedly-(in)variant operand.  Explicit
-    # pvary/tp_entry_mark insertions from this module are value-correct by
-    # construction but can reach those rules through staged jaxprs (scan /
-    # remat bodies) where the tracked replication is approximate; make the
-    # rules permissive set-algebra instead of prove-or-raise.
-    def _psum2_check_permissive(mesh, *in_rep, axes, axis_index_groups):
-        in_rep = tuple(set(mesh.axis_names) if r is None else r for r in in_rep)
-        return [r | set(axes) for r in in_rep]
-
-    def _pbroadcast_check_permissive(mesh, *in_rep, axes, axis_index_groups):
-        in_rep = tuple(set(mesh.axis_names) if r is None else r for r in in_rep)
-        return [r - set(axes) for r in in_rep]
-
-    # cond's checker demands *identical* branch replication and raises
-    # otherwise (e.g. a shared-attention branch vs. an identity branch in
-    # the hybrid architectures); the rewrite rule next to it already knows
-    # the right answer — meet the branches' replication sets.
-    def _cond_check_permissive(mesh, *in_rep, branches):
-        pred_rep, *args_rep = in_rep
-        out_rep = None
-        for branch in branches:
-            r = list(_smod._check_rep(mesh, branch.jaxpr, args_rep))
-            if out_rep is None:
-                out_rep = r
-            else:
-                out_rep = [a & b if (a is not None and b is not None) else None
-                           for a, b in zip(out_rep, r)]
-        pred = set(mesh.axis_names) if pred_rep is None else pred_rep
-        return [r & pred if r is not None else None for r in out_rep]
-
-    import functools as _ft
-    from jax._src.lax.control_flow import conditionals as _conditionals
-
-    _smod._check_rules[_smod.psum2_p] = _psum2_check_permissive
-    _smod._check_rules[_smod.pbroadcast_p] = _pbroadcast_check_permissive
-    _smod._check_rules[_conditionals.cond_p] = _cond_check_permissive
-    # register_norewrite froze the original checkers into the rewrite rules'
-    # partials at import time; rebind them onto the permissive versions.
-    _smod._rewrite_rules[_smod.psum2_p] = _ft.partial(
-        _smod._no_rewrite, _smod.psum2_p, _psum2_check_permissive)
-    _smod._rewrite_rules[_smod.pbroadcast_p] = _ft.partial(
-        _smod._no_rewrite, _smod.pbroadcast_p, _pbroadcast_check_permissive)
-
-    # Pallas kernels (kernels/*.py) run inside the shard_map'd train step but
-    # pre-vma shard_map ships no replication rule for pallas_call, so
-    # check_rep=True raises at trace time.  A Pallas call is collective-free
-    # per-device compute: its outputs are varying wherever any input is
-    # varying — the meet of the input replication sets — and the rewrite only
-    # needs to pbroadcast mixed-replication inputs down to that meet (a
-    # value-identity), exactly what _standard_rewrite_rule does.
-    try:
-        from jax._src.pallas.pallas_call import pallas_call_p as _pallas_call_p
-    except ImportError:  # pallas not present on this build
-        _pallas_call_p = None
-
-    if _pallas_call_p is not None:
-        def _pallas_check_permissive(mesh, *in_rep, **params):
-            known = [r for r in in_rep if r is not None]
-            return set.intersection(*known) if known else set(mesh.axis_names)
-
-        _smod._check_rules[_pallas_call_p] = _pallas_check_permissive
-        _smod._rewrite_rules[_pallas_call_p] = _ft.partial(
-            _smod._standard_rewrite_rule, _pallas_call_p)
-
-
-def _install_vma_style_psum_transpose() -> None:
-    """Pre-vma JAX only: give ``psum`` the vma-era transpose semantics.
-
-    New JAX types collectives with varying-manual-axes and transposes
-    ``psum``(varying -> invariant) to ``pvary`` — a value-identity: the
-    cotangent of an all-reduce *output* (replicated) is handed unchanged to
-    each shard's *partial*, which is the Megatron f/g convention these
-    collective-explicit programs are written against.  Pre-vma JAX instead
-    transposes psum to psum (the pmap-era sum convention): correct for the
-    functional "sum of every device's seeded output", but off by axis-size
-    factors for programs that treat per-device grads as partials and reduce
-    explicitly.  shard_map's check_rep rewriter fixes this for operations it
-    traces directly (psum -> pbroadcast+psum2, whose transposes pair
-    correctly), but AD performed *inside* the shard_map body (jax.grad /
-    jax.vjp, scan and remat bodies) stages tangent psums above the rewriter,
-    where the raw rule applies.
-
-    Every other collective (all_gather, psum_scatter, all_to_all, ppermute)
-    transposes identically under both conventions; psum is the one
-    divergence, so patching its transpose to the value-identity reproduces
-    vma AD semantics exactly.  Positional-axes psum (unused here) keeps the
-    raw rule.
-    """
-    from jax._src.interpreters import ad as _src_ad
-    from jax._src.lax import parallel as _lax_parallel
-
-    raw_rule = _src_ad.primitive_transposes[_lax_parallel.psum_p]
-
-    def _psum_transpose_vma_style(cts, *args, axes, axis_index_groups):
-        if any(isinstance(a, int) for a in axes):  # positional: raw semantics
-            return raw_rule(cts, *args, axes=axes,
-                            axis_index_groups=axis_index_groups)
-        return [_src_ad.Zero(arg.aval) if type(ct) is _src_ad.Zero else ct
-                for ct, arg in zip(cts, args)]
-
-    _src_ad.primitive_transposes[_lax_parallel.psum_p] = \
-        _psum_transpose_vma_style
-
-
-if not HAS_VMA and not HAS_NATIVE_SHARD_MAP:
-    import jax.experimental.shard_map as _sm_internal
-    _relax_pre_vma_rep_checker()
-    _install_vma_style_psum_transpose()
-    # Newer JAX defaults to the partitionable threefry implementation, whose
-    # values are invariant to how a computation is sharded; 0.4.x defaults to
-    # the legacy one, which makes jit(init, out_shardings=...) draw different
-    # parameters per mesh shape.  Align the default so initialisation is
-    # bit-stable across the supported JAX range.
-    if not jax.config.jax_threefry_partitionable:
-        jax.config.update("jax_threefry_partitionable", True)
-else:
-    _sm_internal = None
-
-
-# ---------------------------------------------------------------------------
-# vma typing (varying-manual-axes)
-# ---------------------------------------------------------------------------
 def vma_of(x) -> frozenset:
-    """Axes ``x`` is typed as varying over.
-
-    On vma JAX this reads the aval type.  On pre-vma JAX the same
-    information lives on the check_rep rewriter's tracers (``rep`` = the
-    axes a value is *replicated* over, the complement of vma); values not
-    under the rewrite trace (nested trace levels, plain arrays) report the
-    empty set, which composes with ``pvary``'s already-varying filter below.
-    """
-    if HAS_VMA:
-        return frozenset(getattr(jax.typeof(x), "vma", ()) or ())
-    if _sm_internal is not None and isinstance(x, _sm_internal.RewriteTracer):
-        mesh_axes = frozenset(x._trace.mesh.axis_names)
-        rep = mesh_axes if x.rep is None else frozenset(x.rep)
-        return mesh_axes - rep
-    return frozenset()
+    """Mesh axes ``x`` is typed as varying over (empty outside shard_map)."""
+    return frozenset(getattr(jax.typeof(x), "vma", ()) or ())
 
 
 def pvary(x, axes):
-    """``lax.pvary`` where vma typing exists; the check_rep rewriter's
-    ``pbroadcast`` on pre-vma JAX.
-
-    These are the *same operation* under two names (pvary is the vma-era
-    rename of pbroadcast): value-identity, marks the value device-varying,
-    transposes to the invariant-psum.  Axes the value is already varying
-    over are filtered out (pvary semantics); on pre-vma JAX, values outside
-    the rewrite trace are left untouched — the rewriter re-derives their
-    replication itself.
-    """
+    """Mark ``x`` varying (``lax.pcast(..., to="varying")``) over the axes of
+    ``axes`` it does not already vary over; ``None``/empty names are
+    dropped.  Needed wherever fresh zeros meet mesh-varying values in a scan
+    carry under shard_map's vma typing."""
     axes = tuple(a for a in axes if a) if isinstance(axes, (tuple, list)) \
         else ((axes,) if axes else ())
-    if not axes:
-        return x
-    if HAS_VMA:
-        have = vma_of(x)
-        need = tuple(a for a in axes if a not in have)
-        return _pvary_impl(x, need) if need else x
-    if _sm_internal is not None and isinstance(x, _sm_internal.RewriteTracer):
-        rep = set(x._trace.mesh.axis_names) if x.rep is None else x.rep
-        need = tuple(a for a in axes if a in rep)
-        return _sm_internal.pbroadcast(x, need) if need else x
-    return x
+    need = tuple(a for a in axes if a not in vma_of(x))
+    return lax.pcast(x, need, to="varying") if need else x
 
 
-def pvary_missing(x, axes):
-    """Mark ``x`` varying over ``axes`` (no-op for axes already varying or
-    absent).  Needed wherever fresh zeros meet mesh-varying values in a scan
-    carry under shard_map's vma typing."""
-    return pvary(x, tuple(a for a in axes if a))
+pvary_missing = pvary     # the name most call sites use
 
 
 def match_vma(value, ref):
@@ -310,99 +54,50 @@ def match_vma(value, ref):
     return pvary_missing(value, tuple(vma_of(ref)))
 
 
-def tp_entry_mark(x, axis_name):
-    """Pre-vma JAX only: mark a tensor-parallel *branch input* device-varying
-    over the model axis — the Megatron "f" collective (identity forward,
-    cotangent all-reduce backward).
+def out_struct(shape, dtype, *like) -> jax.ShapeDtypeStruct:
+    """A ``pallas_call`` out_shape that varies over every mesh axis any of
+    ``like`` varies over (a kernel is collective-free per-device compute)."""
+    vma = frozenset().union(*(vma_of(x) for x in like))
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
-    vma JAX inserts the equivalent ``pvary`` automatically, op by op, the
-    moment an invariant activation meets a model-sharded weight, and its
-    transpose (an invariant-psum) is what completes the activation cotangent
-    across model shards.  Pre-vma AD has no typing to trigger the insertion,
-    so the block entries state it explicitly; without it, activation
-    cotangents inside TP blocks stay per-shard partials and every gradient
-    upstream of the block silently loses its cross-shard terms.
 
-    Placement rule: on the *branch* input of a block (the normed activation
-    entering the sharded projections), never on the residual trunk — the
-    trunk cotangent is already complete, and an extra transpose-psum there
-    would overcount it.  Replicated weights living inside a marked block
-    (MoE router, mamba B/C, rwkv mixes) then produce per-shard partial
-    gradients; the gradient reduction completes them (see accumulation.py).
+def _lower_cpu_pallas_without_vma_check() -> None:
+    """Let interpret-mode kernels lower inside a ``check_vma=True`` shard_map.
 
-    No-op on vma JAX (the type system does this itself) and when the model
-    axis is absent.
+    On the CPU a ``pallas_call`` lowers through Pallas' HLO interpreter,
+    which re-traces the kernel body on the enclosing shard_map's vma-typed
+    operands while its own grid indices carry no vma; the first ref slice
+    then fails the vma match.  vma is typing only, so the interpreter is
+    handed the same avals without it and emits the same HLO.  The rule is
+    registered for the CPU platform alone: the TPU lowering (Mosaic) is
+    untouched.
     """
-    if HAS_VMA or not axis_name or _sm_internal is None:
-        return x
-    axes = axis_name if isinstance(axis_name, tuple) else (axis_name,)
-    return _sm_internal.pbroadcast(x, axes)
+    from jax._src.interpreters import mlir
+    from jax._src.pallas import pallas_call as _pc
+
+    def strip(avals):
+        return [a.update(vma=frozenset()) if getattr(a, "vma", None) else a
+                for a in avals]
+
+    def rule(ctx, *args, **params):
+        ctx = ctx.replace(avals_in=strip(ctx.avals_in),
+                          avals_out=strip(ctx.avals_out))
+        return _pc._pallas_call_lowering(ctx, *args, **params)
+
+    mlir.register_lowering(_pc.pallas_call_p, rule, platform="cpu")
 
 
-# ---------------------------------------------------------------------------
-# scan / checkpoint (AD-safe across API generations)
-# ---------------------------------------------------------------------------
-def scan(f, init, xs=None, length=None, reverse: bool = False):
-    """``lax.scan``, as one stable indirection point for the AD-bearing
-    scans of the distributed programs (accumulation / pipeline / MoE
-    dispatch).  With the vma-style psum transpose installed (see above),
-    scan-body AD is convention-consistent on both API generations, so this
-    is a plain alias today; it stays the seam where a pre-vma unroll could
-    be reinstated if a future divergence needs it.
-    """
-    return lax.scan(f, init, xs, length=length, reverse=reverse)
-
-
-def checkpoint(f, **kwargs):
-    """``jax.checkpoint`` through the same stable seam as ``scan``."""
-    return jax.checkpoint(f, **kwargs)
-
-
-# ---------------------------------------------------------------------------
-# all_gather_invariant
-# ---------------------------------------------------------------------------
-_agi = getattr(lax, "all_gather_invariant", None)
-if _agi is None:
-    try:  # not yet public on some versions
-        from jax._src.lax.parallel import all_gather_invariant as _agi
-    except ImportError:
-        _agi = None
+_lower_cpu_pallas_without_vma_check()
 
 
 def all_gather_invariant(x, axis_name, *, axis: int = 0, tiled: bool = False):
-    """Varying -> invariant gather.  0.4.x ``lax.all_gather`` already has the
-    invariant typing (out_rep gains the axis; transpose is a slice, no psum),
-    so it is the exact equivalent there."""
-    if _agi is not None:
-        return _agi(x, axis_name, axis=axis, tiled=tiled)
-    return lax.all_gather(x, axis_name, axis=axis, tiled=tiled)
+    """Varying -> invariant all-gather (transposes to a slice, no psum)."""
+    return _agi(x, axis_name, axis=axis, tiled=tiled)
 
 
-# ---------------------------------------------------------------------------
-# Mesh construction
-# ---------------------------------------------------------------------------
-def make_mesh(axis_shapes, axis_names, *, devices=None, axis_types=None):
-    """``jax.make_mesh`` with the ``axis_types`` kwarg portably applied.
-
-    All meshes in this repo are fully Auto (shard_map handles the manual
-    axes); pre-AxisType JAX has no notion of Explicit axes so dropping the
-    kwarg there is exact.
-    """
-    kwargs: dict[str, Any] = {}
-    if devices is not None:
-        kwargs["devices"] = devices
-    if HAS_AXIS_TYPE:
-        if axis_types is None:
-            axis_types = (jax.sharding.AxisType.Auto,) * len(tuple(axis_names))
-        try:
-            return jax.make_mesh(axis_shapes, axis_names,
-                                 axis_types=axis_types, **kwargs)
-        except TypeError:  # AxisType exists but make_mesh predates the kwarg
-            pass
-    if hasattr(jax, "make_mesh"):
-        return jax.make_mesh(axis_shapes, axis_names, **kwargs)
-    # last-ditch fallback for very old JAX: build the Mesh by hand
-    import numpy as np
-    devs = np.asarray(devices if devices is not None else jax.devices())
-    return jax.sharding.Mesh(devs.reshape(tuple(axis_shapes)),
-                             tuple(axis_names))
+def make_mesh(axis_shapes, axis_names, *, devices=None):
+    """``jax.make_mesh`` with every axis Auto (shard_map handles the manual
+    axes; JAX's own default is Explicit)."""
+    auto = (jax.sharding.AxisType.Auto,) * len(tuple(axis_names))
+    return jax.make_mesh(axis_shapes, axis_names, axis_types=auto,
+                         devices=devices)

@@ -78,34 +78,6 @@ def split_tree(params: PyTree) -> tuple[PyTree, PyTree]:
     return outer, params["layers"]
 
 
-# Replicated weights that live INSIDE a tensor-parallel block (downstream of
-# its compat.tp_entry_mark): on pre-vma JAX their per-shard gradients are
-# partials and need one model-axis psum at reduction time.  On vma JAX the
-# auto-inserted pvary transposes already complete them (and psumming again
-# would double-count), so this set is consulted only when compat.HAS_VMA is
-# False.  Leaves: MoE router, mamba B/C projections, rwkv time/channel mixes.
-_PRE_VMA_BLOCK_REPLICATED = frozenset(
-    {"router", "w_B", "w_C", "mix", "cm_mix", "cm_r"})
-
-
-def _needs_pre_vma_model_psum(path, axis: AxisCtx) -> bool:
-    return (not compat.HAS_VMA and axis.model is not None
-            and getattr(path[-1], "key", None) in _PRE_VMA_BLOCK_REPLICATED)
-
-
-def _complete_block_replicated_grads(grads: PyTree, axis: AxisCtx) -> PyTree:
-    """Pre-vma: finish the partial gradients of in-block replicated leaves."""
-    if compat.HAS_VMA or axis.model is None:
-        return grads
-
-    def fix(path, g):
-        if _needs_pre_vma_model_psum(path, axis):
-            return lax.psum(g, axis.model)
-        return g
-
-    return jax.tree_util.tree_map_with_path(fix, grads)
-
-
 # ---------------------------------------------------------------------------
 # Gather / reduce adapters (partitioned vs replicated storage)
 # ---------------------------------------------------------------------------
@@ -264,10 +236,10 @@ def make_grad_fn(cfg: ModelConfig, axis: AxisCtx, acc: AccumConfig,
             return (x, aux + a), None
 
         if acc.remat:
-            body = compat.checkpoint(body)
+            body = jax.checkpoint(body)
         aux0 = zp.pvary_missing(jnp.zeros((), jnp.float32),
                                 (axis.data, axis.pod))
-        (x, aux), _ = compat.scan(body, (x, aux0),
+        (x, aux), _ = lax.scan(body, (x, aux0),
                                (layers_storage, windows, flags))
         x = apply_norm(cfg, outer_g["final_norm"], x)
         nll = T.head_loss(cfg, outer_g, x, mb, axis)
@@ -301,8 +273,7 @@ def make_grad_fn(cfg: ModelConfig, axis: AxisCtx, acc: AccumConfig,
             {k: v for k, v in T.param_specs(cfg, axis.tp).items() if k != "layers"},
             layers=T.param_specs(cfg, axis.tp)["layers"])
         zeros = grad_zeros(storage, sspecs)
-        grads, (nlls, auxs) = compat.scan(body, zeros, batch)
-        grads = _complete_block_replicated_grads(grads, axis)
+        grads, (nlls, auxs) = lax.scan(body, zeros, batch)
         if not acc.partitioned:
             outer_grads, layer_grads = split_tree(grads)
             grads = dict(reduce_outer_grad(outer_grads),
@@ -323,7 +294,7 @@ def make_grad_fn(cfg: ModelConfig, axis: AxisCtx, acc: AccumConfig,
         def embed_one(_, mb):
             return None, T.embed_inputs(cfg, outer_g, mb, axis)
 
-        _, (X, POS) = compat.scan(embed_one, None, batch)  # [M,mb,S,D], [M,mb,S]
+        _, (X, POS) = lax.scan(embed_one, None, batch)  # [M,mb,S,D], [M,mb,S]
 
         # ---- forward: layer-major scan, keep boundary checkpoints ---------
         seq_len = X.shape[-2]
@@ -358,11 +329,11 @@ def make_grad_fn(cfg: ModelConfig, axis: AxisCtx, acc: AccumConfig,
                                       use_pallas=acc.use_pallas)
                 return carry2 + a, x2
 
-            aux_l, x_new = compat.scan(one_mb, vary_dp(jnp.zeros((), jnp.float32)),
+            aux_l, x_new = lax.scan(one_mb, vary_dp(jnp.zeros((), jnp.float32)),
                                     (x_all, POS))
             return (x_new, aux + aux_l), ckpt_slice(x_all)  # ys: checkpoint
 
-        (xL, aux_total), CKPT = compat.scan(
+        (xL, aux_total), CKPT = lax.scan(
             fwd_layer, (X, vary_dp(jnp.zeros((), jnp.float32))),
             (layers_s, windows, flags))
 
@@ -405,7 +376,7 @@ def make_grad_fn(cfg: ModelConfig, axis: AxisCtx, acc: AccumConfig,
                      None if tied else grad_zeros(outer_g["head"],
                                                   outer_specs["head"]),
                      grad_zeros(outer_g["embed"], outer_specs["embed"]))
-        (dfn, dhead, demb), (dX, nlls) = compat.scan(head_body, head_acc0,
+        (dfn, dhead, demb), (dX, nlls) = lax.scan(head_body, head_acc0,
                                                   (batch, xL))
 
         # ---- backward: reverse layer-major scan -----------------------------
@@ -436,10 +407,9 @@ def make_grad_fn(cfg: ModelConfig, axis: AxisCtx, acc: AccumConfig,
                     lambda u, v: u + v.astype(jnp.float32), a, b)
                 return (add(dw_l, dlp), add(dsh_acc, dsh)), dxin
 
-            (dw_l, dshared_acc), dx_prev = compat.scan(
+            (dw_l, dshared_acc), dx_prev = lax.scan(
                 one_mb, (grad_zeros(lp, lspecs), dshared_acc),
                 (x_in_all, POS, dx_all))
-            dw_l = _complete_block_replicated_grads(dw_l, axis)
             dw_store = reduce_layer_grad(dw_l)    # psum_scatter once per layer
             if layer_update is not None:
                 # fused optimizer: consume the layer gradient immediately
@@ -456,11 +426,11 @@ def make_grad_fn(cfg: ModelConfig, axis: AxisCtx, acc: AccumConfig,
         shared_zero = grad_zeros(shared_g, outer_specs.get("shared", {}))
         if layer_update is not None:
             mu_l, nu_l = opt_layers
-            (dX0, dshared), (new_layers, new_mu, new_nu) = compat.scan(
+            (dX0, dshared), (new_layers, new_mu, new_nu) = lax.scan(
                 bwd_layer, (dX, shared_zero),
                 (layers_s, windows, flags, CKPT, mu_l, nu_l), reverse=True)
         else:
-            (dX0, dshared), layer_grads = compat.scan(
+            (dX0, dshared), layer_grads = lax.scan(
                 bwd_layer, (dX, shared_zero),
                 (layers_s, windows, flags, CKPT), reverse=True)
 
@@ -477,7 +447,7 @@ def make_grad_fn(cfg: ModelConfig, axis: AxisCtx, acc: AccumConfig,
             return jax.tree.map(lambda u, v: u + v.astype(jnp.float32),
                                 demb_acc, de), None
 
-        demb, _ = compat.scan(emb_body, demb, (batch, dX0))
+        demb, _ = lax.scan(emb_body, demb, (batch, dX0))
 
         outer_grads = {"embed": demb, "final_norm": dfn, "shared": dshared}
         if dhead is not None:

@@ -49,9 +49,7 @@ ZeRO-partitioned storage gathers each local chunk's weights ONCE per pass
 (V all-gathers per leaf = the layered-accumulation frequency; modular V=K
 keeps the K-gathers-per-leaf jaxpr pin) at the tick-table's gather
 boundaries, and reduce-scatters each chunk's gradient once at the end of
-the pass.  On pre-vma JAX the in-block model-replicated leaves get
-``compat.tp_entry_mark`` on the chunk weights inside the per-tick VJP, so
-the pull itself completes their partial gradients over `model`.
+the pass.
 """
 from __future__ import annotations
 
@@ -64,7 +62,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.compat import pvary_missing
 from repro.core.schedules import PipeSpec
 from repro.models import transformer as T
@@ -300,8 +297,6 @@ def _make_tick_grad_fn(cfg: ModelConfig, axis: AxisCtx, spec: PipeSpec,
     shapes.  Batch leaves are [M, mb_local, ...] (replicated over `stage`).
     """
     from repro.core import partition as zp
-    from repro.core.accumulation import (_complete_block_replicated_grads,
-                                         _needs_pre_vma_model_psum)
     from repro.planner import simulator as simlib
 
     if table is None:
@@ -340,19 +335,6 @@ def _make_tick_grad_fn(cfg: ModelConfig, axis: AxisCtx, spec: PipeSpec,
     full_tmpl = jax.eval_shape(
         lambda: T.init_params(cfg, jax.random.PRNGKey(0)))
     outer_tmpl = {k: v for k, v in full_tmpl.items() if k != "layers"}
-
-    def mark_chunk(w_c):
-        """Pre-vma: tp_entry_mark the in-block model-replicated chunk leaves
-        INSIDE the per-tick VJP, so the pull's transpose is the model psum
-        completing their partial gradients (PR-5 invariant)."""
-        if not partitioned:
-            return w_c
-
-        def m(path, w):
-            if _needs_pre_vma_model_psum(path, axis):
-                return compat.tp_entry_mark(w, axis.model)
-            return w
-        return jax.tree_util.tree_map_with_path(m, w_c)
 
     def grad_zeros(tree, specs):
         """f32 zero accumulators whose vma matches the executor's gradient
@@ -428,7 +410,7 @@ def _make_tick_grad_fn(cfg: ModelConfig, axis: AxisCtx, spec: PipeSpec,
         def embed_one(_, mb):
             return None, T.embed_inputs(cfg, outer_g, mb, axis)
 
-        _, (X0, POS) = compat.scan(embed_one, None, batch)   # [M, mb, Sq, D]
+        _, (X0, POS) = lax.scan(embed_one, None, batch)   # [M, mb, Sq, D]
         pos = POS[0]                                         # identical per mb
 
         n_tok = jnp.sum(batch["mask"].astype(jnp.float32))
@@ -527,8 +509,6 @@ def _make_tick_grad_fn(cfg: ModelConfig, axis: AxisCtx, spec: PipeSpec,
                 wbuf)
 
             def chunk_f(w_c, sh, xc):
-                w_c = mark_chunk(w_c)
-
                 def layer_step(xc, j):
                     lp = jax.tree.map(lambda p: p[j], w_c)
                     lid = g * k_c + j
@@ -537,7 +517,7 @@ def _make_tick_grad_fn(cfg: ModelConfig, axis: AxisCtx, spec: PipeSpec,
                         window=windows[lid], shared_flag=flags[lid],
                         axis=axis)
                     return x2, None
-                y, _ = compat.scan(layer_step, xc, jnp.arange(k_c))
+                y, _ = lax.scan(layer_step, xc, jnp.arange(k_c))
                 return y
 
             y, pull = jax.vjp(chunk_f, w_chunk, shared_g, x)
@@ -635,7 +615,7 @@ def _make_tick_grad_fn(cfg: ModelConfig, axis: AxisCtx, spec: PipeSpec,
             return jax.tree.map(lambda u, w: u + w.astype(jnp.float32),
                                 demb_acc, de), None
 
-        demb, _ = compat.scan(emb_body, demb, (batch, dX0))
+        demb, _ = lax.scan(emb_body, demb, (batch, dX0))
 
         # ---- reductions ---------------------------------------------------
         outer_grads = {"embed": demb, "final_norm": dfn, "shared": dsh}
@@ -643,11 +623,6 @@ def _make_tick_grad_fn(cfg: ModelConfig, axis: AxisCtx, spec: PipeSpec,
             outer_grads["head"] = dhead
         outer_grads = {k: v for k, v in outer_grads.items()
                        if k in outer_store}
-        # pre-vma completion for any in-block replicated OUTER leaves
-        # (shared attention mixes); partitioned layer chunks were completed
-        # inside the per-tick VJP by mark_chunk
-        outer_grads = _complete_block_replicated_grads(outer_grads, axis)
-
         def reduce_outer(g):
             # outer leaves are stage-replicated but their partials live on
             # the stages that used them (loss stage for embed/head/norm,
@@ -675,8 +650,6 @@ def _make_tick_grad_fn(cfg: ModelConfig, axis: AxisCtx, spec: PipeSpec,
                 return jnp.concatenate(parts, axis=0)[None]
             layer_grads = jax.tree.map(scatter_leaf, dW)
         else:
-            dW = _complete_block_replicated_grads(dW, axis)
-
             def reduce_layer(g):
                 if axis.data:
                     g = lax.psum(g, axis.data)
@@ -748,7 +721,7 @@ def _make_tick_grad_fn(cfg: ModelConfig, axis: AxisCtx, spec: PipeSpec,
                     wbuf = update_wbuf(wbuf, gather_chunk(params, v2), v2)
             if t1 > t0:
                 xs = {k: r[t0:t1] for k, r in ROWS.items()}
-                carry, _ = compat.scan(make_tick(ctx, wbuf), carry, xs)
+                carry, _ = lax.scan(make_tick(ctx, wbuf), carry, xs)
         return epilogue(ctx, carry, params)
 
     return PipelineExecutor(

@@ -8,7 +8,7 @@ statically, collectives carry their mesh axis names, and ``dot_general``
 shapes give exact MXU FLOPs.  All shapes inside ``shard_map`` are per-device,
 so every figure below is already per-chip.
 
-Terms (TPU v5e-class constants):
+Terms (the planner's modelled target, the TPU v5e row of ``PEAKS``):
   compute    = dot_flops / 197e12            (bf16 peak per chip)
   memory     = hbm_bytes / 819e9             (HBM bandwidth)
   collective = sum_axis wire_bytes / 50e9    (ICI per link; pod axis reported
@@ -36,9 +36,18 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 
-# --- hardware constants (TPU v5e-class target) ------------------------------
-PEAK_FLOPS = 197e12        # bf16 per chip
-HBM_BW = 819e9             # bytes/s per chip
+# --- hardware constants ------------------------------------------------------
+# Published per-chip peaks, keyed by ``jax.Device.device_kind``.  Source:
+# Google Cloud documentation, "TPU v5e" (197 TFLOP/s bf16, 819 GB/s HBM).
+# A device kind that is not here has no peak: ``peak_of`` raises.
+PEAKS = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bw": 819e9},
+}
+
+# the chip the planner models: its row of PEAKS, named explicitly
+TARGET_KIND = "TPU v5 lite"
+PEAK_FLOPS = PEAKS[TARGET_KIND]["flops"]     # bf16 per chip
+HBM_BW = PEAKS[TARGET_KIND]["hbm_bw"]        # bytes/s per chip
 ICI_BW = 50e9              # bytes/s per link
 DCN_BW = 6.25e9            # bytes/s per chip across pods (50 Gb/s assumption)
 
@@ -207,13 +216,22 @@ def model_flops_decode(cfg, global_batch: int) -> float:
     return 2.0 * cfg.param_count(active_only=True) * global_batch
 
 
-def mfu(flops_per_step: float, step_time_s: float, *, n_devices: int = 1,
-        peak_flops: float | None = None) -> float:
+def peak_of(device_kind: str) -> dict:
+    """The ``PEAKS`` row of ``device_kind``; an unknown kind raises."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peak for device kind {device_kind!r} "
+                         f"(known: {sorted(PEAKS)})") from None
+
+
+def mfu(flops_per_step: float, step_time_s: float, *, device_kind: str,
+        n_devices: int = 1) -> float:
     """Model-flops utilization: model flops of one step over the hardware
-    flops the mesh could have delivered in its wall time.  The denominator's
-    peak defaults to PEAK_FLOPS (one chip, bf16) — the telemetry layer
-    (obs/metrics.py) reports this against the 6ND numerator above."""
-    peak = PEAK_FLOPS if peak_flops is None else peak_flops
-    if step_time_s <= 0 or peak <= 0 or n_devices <= 0:
+    flops ``n_devices`` chips of ``device_kind`` could have delivered in its
+    wall time — the telemetry layer (obs/metrics.py) reports this against
+    the 6ND numerator above.  An unknown device kind raises."""
+    peak = peak_of(device_kind)["flops"]
+    if step_time_s <= 0 or n_devices <= 0:
         return 0.0
     return flops_per_step / (step_time_s * n_devices * peak)

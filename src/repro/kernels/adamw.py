@@ -28,6 +28,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro import compat
+
 _LANES = 128
 
 
@@ -93,9 +95,8 @@ def adamw_update(p, m, v, g, scalars, *, b1: float, b2: float, eps: float,
         grid=grid,
         in_specs=[pl.BlockSpec((4,), lambda i: (0,)), spec, spec, spec, spec],
         out_specs=[spec, spec, spec],
-        out_shape=[jax.ShapeDtypeStruct(p2.shape, p.dtype),
-                   jax.ShapeDtypeStruct(p2.shape, m.dtype),
-                   jax.ShapeDtypeStruct(p2.shape, v.dtype)],
+        out_shape=[compat.out_struct(p2.shape, x.dtype, p, m, v, g, scalars)
+                   for x in (p, m, v)],
         interpret=interpret,
     )(scalars.astype(jnp.float32), p2, m2, v2, g2)
     unflat = lambda x: x.reshape(-1)[:n].reshape(shape)

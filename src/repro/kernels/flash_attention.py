@@ -30,17 +30,18 @@ Backward (the custom-VJP contract, exposed via kernels/ops.py):
     logits; masked probabilities are rebuilt with the exact forward mask
     (causal, window, and the true ``kv_len`` so padded key rows never leak).
 
-Validated in interpret mode on CPU against kernels/ref.py autodiff (the TPU
-target has no runtime here).
+Validated in interpret mode on CPU against kernels/ref.py autodiff;
+tests/test_tpu_compile.py compiles it for a TPU v5e chip.
 """
 from __future__ import annotations
 
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro import compat
 
 NEG_INF = -1.0e38
 
@@ -91,26 +92,28 @@ def _attn_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale: float,
         mask = _block_mask(q_pos, k_pos, causal=causal, window=window,
                            kv_len=kv_len, seq_len=seq_len)
         s = jnp.where(mask, s, NEG_INF)
-        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1))
+        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_cur)
-        p = jnp.exp(s - m_cur[:, None])
-        l_cur = l_prev * alpha + jnp.sum(p, axis=-1)
-        acc = acc * alpha[:, None] + jnp.dot(p, v,
-                                             preferred_element_type=jnp.float32)
+        p = jnp.exp(s - m_cur)
+        l_cur = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc = acc * alpha + jnp.dot(p, v, preferred_element_type=jnp.float32)
         return acc, m_cur, l_cur
 
+    # the running (max, sum) are [block_q, 1] columns: one value per row, in
+    # the sublane orientation the row reductions produce
     acc0 = jnp.zeros((block_q, D), jnp.float32)
-    m0 = jnp.full((block_q,), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((block_q,), jnp.float32)
+    m0 = jnp.full((block_q, 1), NEG_INF, jnp.float32)
+    l0 = jnp.zeros((block_q, 1), jnp.float32)
     acc, m, l = jax.lax.fori_loop(lo, hi, body, (acc0, m0, l0))
     l = jnp.where(l == 0.0, 1.0, l)                    # fully-masked rows
-    o_ref[0, 0] = (acc / l[:, None]).astype(o_ref.dtype)
+    o_ref[0, 0] = (acc / l).astype(o_ref.dtype)
     lse_ref[0, 0] = m + jnp.log(l)
 
 
 def _recompute_p(q, k, lse, q_pos, k_pos, *, scale, causal, window, kv_len,
                  seq_len, softcap):
-    """(p, softcap tanh term) for one tile, from the raw logits and lse.
+    """(p, softcap tanh term) for one tile, from the raw logits and the
+    ``[block_q, 1]`` lse column.
 
     Rows whose forward was fully masked carry ``lse = NEG_INF`` (they only
     exist in the pad region); their probabilities are forced to zero rather
@@ -126,7 +129,7 @@ def _recompute_p(q, k, lse, q_pos, k_pos, *, scale, causal, window, kv_len,
                        kv_len=kv_len, seq_len=seq_len)
     dead = lse <= 0.5 * NEG_INF
     lse_safe = jnp.where(dead, 0.0, lse)
-    p = jnp.where(mask & ~dead[:, None], jnp.exp(s - lse_safe[:, None]), 0.0)
+    p = jnp.where(mask & ~dead, jnp.exp(s - lse_safe), 0.0)
     return p, t
 
 
@@ -137,8 +140,8 @@ def _attn_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     qi = pl.program_id(2)
     q = q_ref[0, 0].astype(jnp.float32)               # [block_q, D]
     do = do_ref[0, 0].astype(jnp.float32)
-    lse = lse_ref[0, 0]                               # [block_q] fp32
-    delta = delta_ref[0, 0]                           # [block_q] fp32
+    lse = lse_ref[0, 0]                               # [block_q, 1] fp32
+    delta = delta_ref[0, 0]                           # [block_q, 1] fp32
     D = q.shape[-1]
     q_pos = qi * block_q + jax.lax.iota(jnp.int32, block_q)
 
@@ -159,7 +162,7 @@ def _attn_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                             causal=causal, window=window, kv_len=kv_len,
                             seq_len=seq_len, softcap=softcap)
         dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None])
+        ds = p * (dp - delta)
         if softcap > 0:
             ds = ds * (1.0 - t * t)                   # tanh chain rule
         return acc + jnp.dot(ds, k, preferred_element_type=jnp.float32)
@@ -202,7 +205,7 @@ def _attn_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                                 seq_len=seq_len, softcap=softcap)
             dv = dv + jnp.dot(p.T, do, preferred_element_type=jnp.float32)
             dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-            ds = p * (dp - delta[:, None])
+            ds = p * (dp - delta)
             if softcap > 0:
                 ds = ds * (1.0 - t * t)
             dk = dk + jnp.dot(ds.T, q, preferred_element_type=jnp.float32)
@@ -223,7 +226,7 @@ def flash_attention_fwd(q, k, v, *, causal: bool = True, window: int = 0,
                         block_q: int = 128, block_k: int = 128,
                         interpret: bool = False):
     """q: [B, S, Hq, D]; k, v: [B, S, Hkv, D] -> (out [B, S, Hq, D],
-    lse [B, Hq, S] fp32).
+    lse [B, Hq, S, 1] fp32).
 
     GQA is handled by head-index mapping in the BlockSpec (no KV materialised
     repeat).  S must be a multiple of the block sizes (the ops wrapper pads);
@@ -238,6 +241,9 @@ def flash_attention_fwd(q, k, v, *, causal: bool = True, window: int = 0,
     block_q = min(block_q, S)
     block_k = min(block_k, S)
     assert S % block_q == 0 and S % block_k == 0, (S, block_q, block_k)
+    # the per-row lse is stored as a [S, 1] column: its blocks are
+    # (block_q, 1), whose last dim is the array's own, which the TPU's
+    # (8, 128) tiling rule accepts where a (1, block_q) row block is refused
 
     # layout: [B, H, S, D] so the grid walks (batch, head, q-block)
     qt = q.transpose(0, 2, 1, 3)
@@ -258,10 +264,10 @@ def flash_attention_fwd(q, k, v, *, causal: bool = True, window: int = 0,
         ],
         out_specs=[
             pl.BlockSpec((1, 1, block_q, D), lambda b, h, i: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda b, h, i: (b, h, i)),
+            pl.BlockSpec((1, 1, block_q, 1), lambda b, h, i: (b, h, i, 0)),
         ],
-        out_shape=[jax.ShapeDtypeStruct((B, Hq, S, D), q.dtype),
-                   jax.ShapeDtypeStruct((B, Hq, S), jnp.float32)],
+        out_shape=[compat.out_struct((B, Hq, S, D), q.dtype, q, k, v),
+                   compat.out_struct((B, Hq, S, 1), jnp.float32, q, k, v)],
         interpret=interpret,
     )(qt, kt, vt)
     return out.transpose(0, 2, 1, 3), lse
@@ -300,9 +306,11 @@ def flash_attention_bwd(q, k, v, out, lse, do, *, causal: bool = True,
     kt = k.transpose(0, 2, 1, 3)                       # [B, Hkv, S, D]
     vt = v.transpose(0, 2, 1, 3)
     dot = do.transpose(0, 2, 1, 3)
-    # delta = rowsum(dO * O): O(S·D) elementwise prologue (plain JAX)
+    # delta = rowsum(dO * O): O(S·D) elementwise prologue (plain JAX), kept
+    # as the same [B, Hq, S, 1] column layout as lse
     delta = jnp.sum(dot.astype(jnp.float32)
-                    * out.transpose(0, 2, 1, 3).astype(jnp.float32), axis=-1)
+                    * out.transpose(0, 2, 1, 3).astype(jnp.float32), axis=-1,
+                    keepdims=True)
 
     statics = dict(scale=scale, block_q=block_q, block_k=block_k, seq_len=S,
                    kv_len=kv_len, causal=causal, window=window,
@@ -316,11 +324,11 @@ def flash_attention_bwd(q, k, v, out, lse, do, *, causal: bool = True,
             pl.BlockSpec((1, 1, S, D), lambda b, h, i: (b, h // rep, 0, 0)),
             pl.BlockSpec((1, 1, S, D), lambda b, h, i: (b, h // rep, 0, 0)),
             pl.BlockSpec((1, 1, block_q, D), lambda b, h, i: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda b, h, i: (b, h, i)),
-            pl.BlockSpec((1, 1, block_q), lambda b, h, i: (b, h, i)),
+            pl.BlockSpec((1, 1, block_q, 1), lambda b, h, i: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, block_q, 1), lambda b, h, i: (b, h, i, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, block_q, D), lambda b, h, i: (b, h, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Hq, S, D), q.dtype),
+        out_shape=compat.out_struct((B, Hq, S, D), q.dtype, q, k, v, do, lse),
         interpret=interpret,
     )(qt, kt, vt, dot, lse, delta)
 
@@ -328,8 +336,8 @@ def flash_attention_bwd(q, k, v, out, lse, do, *, causal: bool = True,
     # over its `rep` replicated heads in-kernel.
     q5 = qt.reshape(B, Hkv, rep, S, D)
     do5 = dot.reshape(B, Hkv, rep, S, D)
-    lse5 = lse.reshape(B, Hkv, rep, S)
-    delta5 = delta.reshape(B, Hkv, rep, S)
+    lse5 = lse.reshape(B, Hkv, rep, S, 1)
+    delta5 = delta.reshape(B, Hkv, rep, S, 1)
     dk, dv = pl.pallas_call(
         functools.partial(_attn_bwd_dkv_kernel, rep=rep, **statics),
         grid=(B, Hkv, S // block_k),
@@ -338,15 +346,15 @@ def flash_attention_bwd(q, k, v, out, lse, do, *, causal: bool = True,
             pl.BlockSpec((1, 1, block_k, D), lambda b, h, i: (b, h, i, 0)),
             pl.BlockSpec((1, 1, block_k, D), lambda b, h, i: (b, h, i, 0)),
             pl.BlockSpec((1, 1, rep, S, D), lambda b, h, i: (b, h, 0, 0, 0)),
-            pl.BlockSpec((1, 1, rep, S), lambda b, h, i: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, rep, S), lambda b, h, i: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, rep, S, 1), lambda b, h, i: (b, h, 0, 0, 0)),
+            pl.BlockSpec((1, 1, rep, S, 1), lambda b, h, i: (b, h, 0, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, block_k, D), lambda b, h, i: (b, h, i, 0)),
             pl.BlockSpec((1, 1, block_k, D), lambda b, h, i: (b, h, i, 0)),
         ],
-        out_shape=[jax.ShapeDtypeStruct((B, Hkv, S, D), k.dtype),
-                   jax.ShapeDtypeStruct((B, Hkv, S, D), v.dtype)],
+        out_shape=[compat.out_struct((B, Hkv, S, D), x.dtype, q, k, v, do, lse)
+                   for x in (k, v)],
         interpret=interpret,
     )(q5, kt, vt, do5, lse5, delta5)
 

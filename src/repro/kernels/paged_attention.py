@@ -34,6 +34,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro import compat
+
 NEG_INF = -1.0e38
 
 
@@ -111,7 +113,8 @@ def paged_attention_decode(q, k_pool, v_pool, block_tables, context_lens, *,
             pl.BlockSpec((1,), lambda r, h: (r,)),
         ],
         out_specs=pl.BlockSpec((1, 1, D), lambda r, h: (r, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((R, Hq, D), q.dtype),
+        out_shape=compat.out_struct((R, Hq, D), q.dtype, q, k_pool, v_pool,
+                                    block_tables, context_lens),
         interpret=interpret,
     )(q, k_pool, v_pool, block_tables.astype(jnp.int32),
       context_lens.astype(jnp.int32))

@@ -20,6 +20,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro import compat
+
 
 def _rmsnorm_kernel(x_ref, s_ref, o_ref, *, eps: float, plus_one: bool):
     x = x_ref[...].astype(jnp.float32)
@@ -52,8 +54,11 @@ def _to_rows(x, block_rows: int | None, interpret: bool):
         rows *= d
     if block_rows is None:
         # interpret mode: one whole tile (XLA elides the full-extent block
-        # copies); compiled TPU path: the VMEM-sized default.
-        block_rows = rows if interpret else 256
+        # copies); compiled TPU path: tiles of at most 512K elements, which
+        # keeps the backward's double-buffered (x, g, dx) blocks and fp32
+        # temporaries inside the 16 MiB scoped VMEM (256 rows of 4096 are
+        # refused there)
+        block_rows = rows if interpret else max(8, min(256, (1 << 19) // D))
     x2 = x.reshape(rows, D)
     block_rows = min(block_rows, rows)
     pad = (-rows) % block_rows
@@ -77,7 +82,7 @@ def rmsnorm(x, scale, *, eps: float = 1e-6, plus_one: bool = False,
         in_specs=[pl.BlockSpec((block_rows, D), lambda i: (i, 0)),
                   pl.BlockSpec((D,), lambda i: (0,))],
         out_specs=pl.BlockSpec((block_rows, D), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct(x2.shape, x.dtype),
+        out_shape=compat.out_struct(x2.shape, x.dtype, x, scale),
         interpret=interpret,
     )(x2, scale)
     if x2.shape[0] != rows:
@@ -95,6 +100,9 @@ def rmsnorm_bwd(x, scale, g, *, eps: float = 1e-6, plus_one: bool = False,
     x2, rows, block_rows = _to_rows(x, block_rows, interpret)
     g2, _, _ = _to_rows(g, block_rows, interpret)
     n_blocks = x2.shape[0] // block_rows
+    # dscale partials are [n_blocks, 1, D]: each grid step writes a (1, D)
+    # block whose second-to-last dim is the array's own (the TPU's (8, 128)
+    # tiling rule refuses a (1, D) block of an [n_blocks, D] array)
     dx, ds_part = pl.pallas_call(
         functools.partial(_rmsnorm_bwd_kernel, eps=eps, plus_one=plus_one),
         grid=(n_blocks,),
@@ -102,11 +110,12 @@ def rmsnorm_bwd(x, scale, g, *, eps: float = 1e-6, plus_one: bool = False,
                   pl.BlockSpec((D,), lambda i: (0,)),
                   pl.BlockSpec((block_rows, D), lambda i: (i, 0))],
         out_specs=[pl.BlockSpec((block_rows, D), lambda i: (i, 0)),
-                   pl.BlockSpec((1, D), lambda i: (i, 0))],
-        out_shape=[jax.ShapeDtypeStruct(x2.shape, x.dtype),
-                   jax.ShapeDtypeStruct((n_blocks, D), jnp.float32)],
+                   pl.BlockSpec((None, 1, D), lambda i: (i, 0, 0))],
+        out_shape=[compat.out_struct(x2.shape, x.dtype, x, scale, g),
+                   compat.out_struct((n_blocks, 1, D), jnp.float32, x, scale,
+                                     g)],
         interpret=interpret,
     )(x2, scale, g2)
     if x2.shape[0] != rows:
         dx = dx[:rows]
-    return dx.reshape(orig_shape), jnp.sum(ds_part, axis=0)
+    return dx.reshape(orig_shape), jnp.sum(ds_part, axis=(0, 1))

@@ -1,8 +1,15 @@
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+# A compile-only rehearsal on 512 virtual CPU devices: it never takes an
+# accelerator, neither here nor in the sweep's child processes (they inherit
+# this environment), and it keeps whatever XLA_FLAGS the caller set.
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
+                           + " --xla_force_host_platform_device_count=512"
+                           ).strip()
 
-# The two lines above MUST run before any other import (jax locks the device
-# count at first init).  Everything below assumes 512 virtual devices.
+# The lines above MUST run before any other import (jax locks the platform
+# and the device count at first init).  Everything below assumes 512
+# virtual devices.
 
 import argparse      # noqa: E402
 import dataclasses   # noqa: E402

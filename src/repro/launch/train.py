@@ -17,6 +17,11 @@ Planner integration (the analysis -> execution loop):
 The plan's execution section supplies arch/mesh/method/partition/
 microbatches/global-batch/seq-len (and steps, unless --steps is passed
 explicitly); explicit CLI flags still win over the plan.
+
+On an accelerator the run keeps its compiled programs in JAX's persistent
+compilation cache (``repro.launch.cache``: ``$JAX_COMPILATION_CACHE_DIR``,
+else ``<checkout>/.jax_cache``).  CPU runs leave the cache as JAX's own
+configuration has it.
 """
 from __future__ import annotations
 
@@ -35,6 +40,7 @@ from repro.core import stepfn
 from repro.core.accumulation import AccumConfig
 from repro.core.schedules import PipeSpec
 from repro.data.synthetic import DataConfig, batch_for
+from repro.launch.cache import enable_compile_cache
 from repro.launch.mesh import make_train_mesh
 from repro.obs import drift as obs_drift
 from repro.obs import metrics as obs_metrics
@@ -195,6 +201,8 @@ def main(argv=None) -> dict:
         apply_plan(args, argv if argv is not None else sys.argv[1:])
     if not args.arch:
         ap.error("--arch required (directly or via --plan)")
+    if jax.default_backend() != "cpu":
+        enable_compile_cache()
 
     cfg = configs.get_config(args.arch, smoke=args.smoke)
     d, m = (int(v) for v in args.mesh.split("x"))
@@ -214,6 +222,7 @@ def main(argv=None) -> dict:
     mesh = make_train_mesh(stages=args.stages, data=d, model=m)
 
     n_devices = args.stages * d * m
+    device_kind = jax.devices()[0].device_kind
     tokens_per_step = args.global_batch * args.seq_len
     sink = obs_metrics.MetricsSink(
         args.metrics,
@@ -221,7 +230,8 @@ def main(argv=None) -> dict:
               "stages": args.stages,
               "schedule": args.schedule if args.stages > 1 else None,
               "global_batch": args.global_batch, "seq_len": args.seq_len,
-              "n_devices": n_devices, "partitioned": partitioned})
+              "n_devices": n_devices, "device_kind": device_kind,
+              "partitioned": partitioned})
     tracer = obs_trace.Tracer() if args.trace else None
 
     def span(name, **kw):
@@ -280,9 +290,11 @@ def main(argv=None) -> dict:
         except (NotImplementedError, ValueError) as e:
             ap.error(f"plan tick table is not executable: {e}")
         with span("build_step"):
+            # params and optimizer state are rebound every step, so the
+            # step donates them: one copy of the state lives on the device
             step = stepfn.build_pipeline_train_step(
                 cfg, mesh, spec, opt_cfg, partitioned=partitioned,
-                donate=False, table=exec_table)
+                donate=True, table=exec_table)
         with span("init_storage"):
             storage = stepfn.init_pipeline_storage(
                 cfg, mesh, jax.random.PRNGKey(args.seed), spec,
@@ -292,7 +304,7 @@ def main(argv=None) -> dict:
                           n_microbatches=args.microbatches)
         with span("build_step"):
             step = stepfn.build_train_step(cfg, mesh, acc, opt_cfg,
-                                           donate=False)
+                                           donate=True)
         with span("init_storage"):
             storage = stepfn.init_storage(cfg, mesh,
                                           jax.random.PRNGKey(args.seed),
@@ -343,7 +355,7 @@ def main(argv=None) -> dict:
                    "mfu": obs_metrics.mfu_estimate(
                        cfg, global_batch=args.global_batch,
                        seq_len=args.seq_len, step_time_s=dt,
-                       n_devices=n_devices)}
+                       n_devices=n_devices, device_kind=device_kind)}
             sink.log(rec)
             history.append(loss)
             if i % args.log_every == 0:
@@ -393,7 +405,8 @@ def main(argv=None) -> dict:
                 result["max_abs_drift"] = rep["max_abs_drift"]
 
         result.update({"arch": args.arch, "first_loss": history[0],
-                       "last_loss": history[-1], "steps": len(history),
+                       "last_loss": history[-1], "losses": history,
+                       "steps": len(history),
                        "seconds": round(time.time() - t_start, 1)})
         print(json.dumps(result))
         return result

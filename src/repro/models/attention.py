@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro import compat
 from repro.models.common import AxisCtx, ModelConfig, apply_rope, dense_init, softcap
 
 PyTree = Any
@@ -167,7 +166,6 @@ def attention_train(cfg: ModelConfig, p: PyTree, x: jnp.ndarray, *,
         use_pallas = cfg.kernels
     if S > CHUNKED_THRESHOLD:
         use_pallas = False
-    x = compat.tp_entry_mark(x, axis.model)
     q, k, v = _project_qkv(cfg, p, x, axis)
     q = apply_rope(q, positions, cfg.rope_theta)
     k = apply_rope(k, positions, cfg.rope_theta)

@@ -16,8 +16,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.compat import (HAS_VMA, match_vma, pvary_missing,  # noqa: F401
-                          tp_entry_mark)
+from repro.compat import match_vma, pvary_missing  # noqa: F401
 
 PyTree = Any
 
@@ -60,8 +59,8 @@ class AxisCtx:
         return n
 
 
-# pvary_missing / match_vma live in repro.compat (they are JAX-version
-# dependent); re-exported above for the existing call sites.
+# pvary_missing / match_vma live in repro.compat (the shard_map/vma seam);
+# re-exported above for the existing call sites.
 
 
 # ---------------------------------------------------------------------------
@@ -365,7 +364,6 @@ def lm_head_loss(cfg: ModelConfig, head: jnp.ndarray, x: jnp.ndarray,
     Returns the summed (not averaged) loss; the caller normalises so that
     micro-batch accumulation stays linear.
     """
-    x = tp_entry_mark(x, axis.model)
     logits = jnp.einsum("bsd,vd->bsv", x, head.astype(x.dtype)).astype(jnp.float32)
     logits = softcap(logits, cfg.final_logit_softcap)
     if axis.model:
@@ -387,12 +385,10 @@ def lm_head_loss(cfg: ModelConfig, head: jnp.ndarray, x: jnp.ndarray,
         picked = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
     nll = (m + jnp.log(se) - picked) * mask.astype(jnp.float32)
     total = jnp.sum(nll)
-    if axis.model and HAS_VMA:
+    if axis.model:
         # value is already replicated across `model` (the stabilizer came from
         # an all_gather); this scalar psum/size only restores the invariant
-        # typing for the vma machinery.  Pre-vma JAX has no such typing to
-        # restore, and there the pair would misweight the backward (the
-        # auto-pvary whose transpose rebalances it is a vma-era insertion).
+        # typing for the vma machinery.
         total = lax.psum(total, axis.model) / lax.psum(1.0, axis.model)
     return total
 
@@ -400,7 +396,6 @@ def lm_head_loss(cfg: ModelConfig, head: jnp.ndarray, x: jnp.ndarray,
 def lm_logits(cfg: ModelConfig, head: jnp.ndarray, x: jnp.ndarray,
               axis: AxisCtx) -> jnp.ndarray:
     """Full logits for decoding: [B, S, V_local] (still vocab-sharded)."""
-    x = tp_entry_mark(x, axis.model)
     logits = jnp.einsum("bsd,vd->bsv", x, head.astype(x.dtype)).astype(jnp.float32)
     return softcap(logits, cfg.final_logit_softcap)
 

@@ -10,7 +10,6 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.models.common import AxisCtx, ModelConfig, activation, dense_init
 
 PyTree = Any
@@ -31,7 +30,6 @@ def init_mlp(cfg: ModelConfig, key, *, d_ff: int | None = None) -> PyTree:
 
 def apply_mlp(cfg: ModelConfig, p: PyTree, x: jnp.ndarray, axis: AxisCtx) -> jnp.ndarray:
     dt = x.dtype
-    x = compat.tp_entry_mark(x, axis.model)
     act = activation(cfg.hidden_act)
     up = jnp.einsum("bsd,df->bsf", x, p["w_up"].astype(dt))
     if cfg.glu:

@@ -19,7 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro import compat
 from repro.models.common import AxisCtx, ModelConfig, activation, dense_init
 from repro.models.mlp import apply_mlp, init_mlp
 
@@ -136,7 +135,7 @@ def _apply_moe_a2a(cfg: ModelConfig, p: PyTree, xt: jnp.ndarray, axis: AxisCtx,
     xs = (xt.reshape(n_chunks, chunk, D),
           weights.reshape(n_chunks, chunk, k),
           ids.reshape(n_chunks, chunk, k))
-    _, yt = compat.scan(one_chunk, None, xs)
+    _, yt = lax.scan(one_chunk, None, xs)
     yt = yt.reshape(-1, D)
     return yt[:T] if pad else yt
 
@@ -153,7 +152,6 @@ def apply_moe(cfg: ModelConfig, p: PyTree, x: jnp.ndarray, axis: AxisCtx,
     B, S, D = x.shape
     T = B * S
     dt = x.dtype
-    x = compat.tp_entry_mark(x, axis.model)
     xt = x.reshape(T, D)
     weights, ids, aux = _router(cfg, p, xt)
 

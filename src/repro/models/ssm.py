@@ -24,7 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro import compat
 from repro.compat import vma_of
 from repro.models.common import (AxisCtx, ModelConfig, dense_init,
                                  pvary_missing, rms_norm)
@@ -146,7 +145,6 @@ def apply_mamba(cfg: ModelConfig, p: PyTree, x: jnp.ndarray, axis: AxisCtx, *,
     B, S, _ = x.shape
     hd = cfg.ssm_head_dim
     dt_ = x.dtype
-    x = compat.tp_entry_mark(x, axis.model)
     xs = jnp.einsum("bsd,df->bsf", x, p["w_x"].astype(dt_))
     z = jnp.einsum("bsd,df->bsf", x, p["w_z"].astype(dt_))
     Bm = jnp.einsum("bsd,dk->bsk", x, p["w_B"].astype(dt_))
@@ -239,7 +237,7 @@ def apply_rwkv(cfg: ModelConfig, p: PyTree, x: jnp.ndarray, axis: AxisCtx, *,
             "x_cm": jnp.zeros((B, D), dt_),
         }
     # ---- time mix ----------------------------------------------------------
-    a = compat.tp_entry_mark(rms_norm(x, p["ln1"]), axis.model)
+    a = rms_norm(x, p["ln1"])
     aprev = _token_shift(a, state["x_tm"] if (decode or have_state) else None)
     mix = p["mix"].astype(dt_)
     xr, xk, xv, xg, xw = (a + mix[i] * (aprev - a) for i in range(5))
@@ -266,7 +264,7 @@ def apply_rwkv(cfg: ModelConfig, p: PyTree, x: jnp.ndarray, axis: AxisCtx, *,
     y = jnp.einsum("bsd,de->bse", o, p["w_time_out"].astype(dt_))
     x = x + axis.psum_model(y)
     # ---- channel mix ---------------------------------------------------------
-    b = compat.tp_entry_mark(rms_norm(x, p["ln2"]), axis.model)
+    b = rms_norm(x, p["ln2"])
     bprev = _token_shift(b, state["x_cm"] if (decode or have_state) else None)
     cmix = p["cm_mix"].astype(dt_)
     xk2 = b + cmix[0] * (bprev - b)

@@ -131,16 +131,26 @@ def resilience_registry() -> Registry:
 # Derived estimates
 # ---------------------------------------------------------------------------
 def mfu_estimate(cfg, *, global_batch: int, seq_len: int, step_time_s: float,
-                 n_devices: int = 1, peak_flops: float | None = None) -> float:
+                 n_devices: int = 1,
+                 device_kind: str | None = None) -> float | None:
     """Model-flops utilization of one optimizer step: the roofline 6ND
     training flops over ``step_time * devices * peak`` (core/roofline.py is
-    the single source for both the numerator model and the device peak)."""
+    the single source for both the numerator model and the device peak).
+
+    ``device_kind`` defaults to the first JAX device's.  A device with no
+    published peak (the CPU among them) has no MFU: the result is None."""
+    import jax
+
     from repro.core import roofline
+    if device_kind is None:
+        device_kind = jax.devices()[0].device_kind
+    if device_kind not in roofline.PEAKS:
+        return None
     if step_time_s <= 0:
         return 0.0
     flops = roofline.model_flops_train(cfg, global_batch, seq_len)
     return roofline.mfu(flops, step_time_s, n_devices=n_devices,
-                        peak_flops=peak_flops)
+                        device_kind=device_kind)
 
 
 def percentiles(values, qs=(50, 95, 99)) -> dict:

@@ -16,6 +16,7 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 PyTree = Any
 
@@ -44,10 +45,21 @@ def schedule(c: AdamConfig, step: jnp.ndarray) -> jnp.ndarray:
 
 
 def adam_init(storage: PyTree, *, moment_dtype="float32") -> PyTree:
+    """Zero moments.  Where the storage is sharded on a mesh, each moment is
+    made directly in its storage leaf's sharding and the step counter
+    replicated on that mesh, so no device ever holds a whole moment."""
     dt = jnp.dtype(moment_dtype)
-    zeros = lambda t: jax.tree.map(lambda l: jnp.zeros(l.shape, dt), t)
+
+    def placed(l):
+        s = getattr(l, "sharding", None)
+        return s if isinstance(s, NamedSharding) else None
+
+    zeros = lambda t: jax.tree.map(
+        lambda l: jnp.zeros(l.shape, dt, device=placed(l)), t)
+    where = [s for s in map(placed, jax.tree.leaves(storage)) if s]
+    step_at = NamedSharding(where[0].mesh, P()) if where else None
     return {"mu": zeros(storage), "nu": zeros(storage),
-            "step": jnp.zeros((), jnp.int32)}
+            "step": jnp.zeros((), jnp.int32, device=step_at)}
 
 
 def adam_step(c: AdamConfig, storage: PyTree, opt: PyTree, grads: PyTree, *,

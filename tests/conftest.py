@@ -8,26 +8,28 @@ os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
-import jax  # noqa: E402
+from repro import compat  # noqa: E402
 
 import pytest  # noqa: E402
 
 
+# every mesh is built as the program builds its own: Auto axes (JAX's own
+# make_mesh defaults to Explicit ones)
 @pytest.fixture(scope="session")
 def mesh22():
-    return jax.make_mesh((2, 2), ("data", "model"))
+    return compat.make_mesh((2, 2), ("data", "model"))
 
 
 @pytest.fixture(scope="session")
 def mesh_stage4():
-    return jax.make_mesh((4,), ("stage",))
+    return compat.make_mesh((4,), ("stage",))
 
 
 @pytest.fixture(scope="session")
 def mesh11():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return compat.make_mesh((1, 1), ("data", "model"))
 
 
 @pytest.fixture(scope="session")
 def mesh_pod():
-    return jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    return compat.make_mesh((2, 2, 2), ("pod", "data", "model"))

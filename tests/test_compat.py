@@ -1,8 +1,4 @@
-"""The JAX-version portability layer (repro.compat) itself.
-
-These run on both CI legs (JAX 0.4.37 and latest), so every assertion must
-hold on the pre-vma emulation path AND the native vma path.
-"""
+"""The shard_map/vma seam (repro.compat) on the installed JAX."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -10,16 +6,54 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from repro import compat
+from repro.kernels import ops
 
 
-def test_feature_flags_are_coherent():
-    # exactly one of the two worlds: native vma surface, or the 0.4.x
-    # emulation (experimental shard_map + no pvary/typeof)
-    if compat.HAS_NATIVE_SHARD_MAP:
-        assert hasattr(jax, "shard_map")
-    else:
-        import jax.experimental.shard_map  # the fallback import must exist
-    assert isinstance(compat.JAX_VERSION, tuple) and len(compat.JAX_VERSION) == 3
+def test_out_struct_carries_input_vma():
+    """A kernel's out_shape varies over the union of its inputs' axes;
+    outside shard_map it varies over nothing."""
+    mesh = compat.make_mesh((2, 2), ("data", "model"))
+    seen = {}
+
+    def f(a, b):
+        seen["both"] = compat.out_struct((2,), jnp.float32, a, b).vma
+        seen["b"] = compat.out_struct((2,), jnp.float32, b).vma
+        return a
+
+    jax.jit(compat.shard_map(f, mesh=mesh, in_specs=(P("data"), P("model")),
+                             out_specs=P("data")))(jnp.ones(4), jnp.ones(4))
+    assert seen == {"both": frozenset({"data", "model"}),
+                    "b": frozenset({"model"})}
+    assert compat.out_struct((3,), jnp.float32, jnp.ones(3)).vma == frozenset()
+
+
+def test_interpret_kernel_grads_inside_check_vma_shard_map():
+    """An interpret-mode Pallas kernel (flash attention, custom VJP) traces,
+    lowers and differentiates inside a check_vma=True shard_map on the CPU,
+    and matches the same kernel outside it."""
+    mesh = compat.make_mesh((2,), ("data",))
+    key = jax.random.PRNGKey(0)
+    q, k, v = (jax.random.normal(jax.random.fold_in(key, i), (2, 16, 2, 8))
+               for i in range(3))
+
+    def loss(q, k, v):
+        return jnp.sum(ops.flash_attention(q, k, v, block_q=8, block_k=8,
+                                           interpret=True) ** 2)
+
+    def sharded(q, k, v):
+        g = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+        return tuple(lax.psum(x, "data") for x in g)
+
+    spec = P("data")
+    got = jax.jit(compat.shard_map(sharded, mesh=mesh,
+                                   in_specs=(spec, spec, spec),
+                                   out_specs=(P(), P(), P())))(q, k, v)
+    halves = [jax.grad(loss, argnums=(0, 1, 2))(q[i:i + 1], k[i:i + 1],
+                                                 v[i:i + 1]) for i in (0, 1)]
+    for j in range(3):
+        np.testing.assert_allclose(np.asarray(got[j]),
+                                   np.asarray(halves[0][j] + halves[1][j]),
+                                   rtol=1e-5, atol=1e-5)
 
 
 def test_shard_map_resolves_and_runs_psum():
@@ -48,7 +82,7 @@ def test_shard_map_check_vma_kwarg_accepted():
 
 
 def test_pvary_is_identity_valued():
-    """compat.pvary only changes typing, never values — on both generations."""
+    """compat.pvary only changes typing, never values."""
     mesh = compat.make_mesh((8,), ("data",))
     x = jnp.arange(8.0)
 
@@ -60,7 +94,7 @@ def test_pvary_is_identity_valued():
     out = jax.jit(compat.shard_map(f, mesh=mesh, in_specs=(P("data"),),
                                    out_specs=P("data")))(x)
     np.testing.assert_allclose(np.asarray(out), np.arange(8.0) + 1.0)
-    # outside any mesh: plain identity on both generations
+    # outside any mesh: plain identity
     np.testing.assert_allclose(np.asarray(compat.pvary(x, ())), np.asarray(x))
     np.testing.assert_allclose(np.asarray(compat.pvary_missing(x, (None,))),
                                np.asarray(x))
@@ -90,9 +124,9 @@ def test_all_gather_invariant_values():
 
 def test_grad_convention_row_parallel():
     """The semantic heart of the layer: Megatron-style TP gradients computed
-    INSIDE shard_map match the single-device reference on both generations
-    (psum transposing to the value-identity, tp_entry_mark supplying the
-    f-collective's backward all-reduce)."""
+    INSIDE shard_map match the single-device reference (psum transposing to
+    the value-identity, the auto-inserted pvary supplying the f-collective's
+    backward all-reduce)."""
     mesh = compat.make_mesh((2, 2), ("data", "model"))
     key = jax.random.PRNGKey(0)
     W = jax.random.normal(key, (8, 16))

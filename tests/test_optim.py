@@ -79,3 +79,25 @@ def test_fused_adam_step_matches_treemap(moment_dtype):
                                    rtol=1e-6, atol=1e-6, err_msg=str(path))
     assert float(outs[False][2]["grad_norm"]) == \
         float(outs[True][2]["grad_norm"])
+
+
+def test_adam_init_lays_moments_out_like_sharded_storage(mesh22):
+    """On a mesh, each moment is made in its storage leaf's sharding and the
+    step counter replicated on the mesh: no device holds a whole moment
+    (made whole on device 0, X_32's Adam moments put 3.7 GB there)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.core import stepfn
+    from repro.models.common import ModelConfig
+
+    cfg = ModelConfig(name="opt-place", arch_type="dense", num_layers=2,
+                      d_model=64, num_heads=4, num_kv_heads=2, d_ff=128,
+                      vocab_size=64, dtype="float32", param_dtype="float32")
+    storage = stepfn.init_storage(cfg, mesh22, jax.random.PRNGKey(0),
+                                  partitioned=True)
+    opt = adam_init(storage, moment_dtype="bfloat16")
+    for s, m, v in zip(*(jax.tree.leaves(t) for t in
+                         (storage, opt["mu"], opt["nu"]))):
+        assert m.sharding == s.sharding and v.sharding == s.sharding
+        assert m.dtype == jnp.bfloat16 and not np.any(np.asarray(m, np.float32))
+    assert opt["step"].sharding == NamedSharding(mesh22, P())

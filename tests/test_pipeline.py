@@ -183,10 +183,9 @@ def test_pipeline_composes_with_tensor_parallelism(sched):
 def test_pipeline_3d_mesh_exercises_completion_psums():
     """The full 3d composition (stage x data x model) on a mamba config:
     its w_B/w_C projections are replicated INSIDE the tensor-parallel block,
-    so on pre-vma JAX their per-shard gradients are partials completed by
-    the model-axis psum in the gradient reduction
-    (accumulation._PRE_VMA_BLOCK_REPLICATED) — previously exercised only on
-    stage x data meshes."""
+    so their per-shard gradients are partials that the transpose of the
+    pvary vma typing inserts completes over `model` — previously exercised
+    only on stage x data meshes."""
     cfg = ModelConfig(name="m3d", arch_type="dense", num_layers=4, d_model=48,
                       d_ff=96, vocab_size=64, dtype="float32",
                       param_dtype="float32", num_heads=0, num_kv_heads=0,

@@ -1,19 +1,19 @@
 #!/usr/bin/env python
-"""Lint gate: the JAX version shims live in ONE file.
+"""Lint gate: the shard_map/vma surface lives in ONE file.
 
-The repo supports JAX 0.4.37 (check_rep-era shard_map) through current
-(vma-typed ``jax.shard_map`` / ``lax.pvary`` / ``jax.typeof``).  Every
-version difference is absorbed by ``src/repro/compat.py``; the rest of
-``src/`` must call ``compat.shard_map`` / ``compat.pvary`` /
-``compat.vma_of`` so a JAX upgrade touches exactly one module.
+``src/repro/compat.py`` is the only module that touches JAX's vma-typed
+shard_map surface (``jax.shard_map`` / ``lax.pcast`` / ``lax.pvary`` /
+``jax.typeof``); the rest of ``src/`` calls ``compat.shard_map`` /
+``compat.pvary`` / ``compat.vma_of`` / ``compat.out_struct``, so a JAX
+upgrade touches exactly one module.
 
 This script fails (exit 1) when any file under ``src/`` other than
 ``compat.py`` uses the raw surface:
 
   * ``jax.shard_map`` / ``jax.experimental.shard_map``
-  * ``lax.pvary`` / ``jax.lax.pvary``
+  * ``lax.pvary`` / ``lax.pcast``
   * ``jax.typeof``
-  * a ``check_rep=`` keyword (the pre-0.6 shard_map spelling)
+  * a ``check_rep=`` keyword (the old shard_map spelling)
 
 Run locally:  python tools/check_compat.py
 CI runs it as the blocking ``lint`` job.
@@ -35,6 +35,7 @@ PATTERNS = (
                 r"from\s+jax\.experimental\.shard_map\s+import|"
                 r"from\s+jax\.experimental\s+import\s+shard_map")),
     ("lax.pvary", re.compile(r"\blax\.pvary\b")),
+    ("lax.pcast", re.compile(r"\blax\.pcast\b")),
     ("jax.typeof", re.compile(r"\bjax\.typeof\b")),
     ("check_rep=", re.compile(r"\bcheck_rep\s*=")),
 )
@@ -73,7 +74,7 @@ def main() -> int:
     if errors:
         print("\n".join(errors))
         print(f"\n{len(errors)} compat violation(s): only src/repro/compat.py "
-              f"may touch the raw shard_map/pvary/typeof surface.")
+              f"may touch the raw shard_map/pvary/pcast/typeof surface.")
         return 1
     print("compat check: OK (all raw shard_map/pvary/typeof uses are in "
           "compat.py)")
