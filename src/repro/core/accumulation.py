@@ -37,6 +37,7 @@ from repro import compat
 from repro.core import partition as zp
 from repro.models import transformer as T
 from repro.models.common import AxisCtx, ModelConfig, apply_norm
+from repro.obs.trace import phase
 
 PyTree = Any
 
@@ -164,8 +165,10 @@ def make_adapters(cfg: ModelConfig, axis: AxisCtx, acc: AccumConfig,
         def reduce_layer_grad(g):
             return jax.tree.map(_reduce, g)
 
-    return (gather_outer, gather_layer, reduce_outer_grad, reduce_layer_grad,
-            outer_specs, lspecs)
+    return (phase("zero_gather")(gather_outer),
+            phase("zero_gather")(gather_layer),
+            phase("zero_reduce")(reduce_outer_grad),
+            phase("zero_reduce")(reduce_layer_grad), outer_specs, lspecs)
 
 
 # ---------------------------------------------------------------------------
@@ -221,6 +224,7 @@ def make_grad_fn(cfg: ModelConfig, axis: AxisCtx, acc: AccumConfig,
             n = n * lax.psum(1.0, axis.pod)
         return n
 
+    @phase("fwd")
     def mb_loss(outer_g, layers_storage, mb, inv_n, aux_scale):
         """One micro-batch forward + loss given gathered outer params and the
         *storage-layout* layer params (gathered inside, per layer)."""
@@ -249,6 +253,10 @@ def make_grad_fn(cfg: ModelConfig, axis: AxisCtx, acc: AccumConfig,
     # ------------------------------------------------------------------
     # standard (batch-major) gradient accumulation
     # ------------------------------------------------------------------
+    # Phases: all "fwd" but the gradient sums ("bwd") and the ZeRO adapters;
+    # under jax.grad the backward reads transpose(...) and the recomputed
+    # forward rematted_computation in op_name.
+    @phase("fwd")
     def standard_grad(storage, batch):
         inv_n = 1.0 / n_global_tokens(batch)
         aux_scale = 1.0 / (M * cfg.num_layers * dp_total())
@@ -266,13 +274,16 @@ def make_grad_fn(cfg: ModelConfig, axis: AxisCtx, acc: AccumConfig,
 
         def body(gacc, mb):
             g, (nll, aux) = gfun(storage, mb)
-            gacc = jax.tree.map(lambda a, b: a + b.astype(jnp.float32), gacc, g)
+            with phase("bwd"):
+                gacc = jax.tree.map(lambda a, b: a + b.astype(jnp.float32),
+                                    gacc, g)
             return gacc, (nll, aux)
 
         sspecs = dict(
             {k: v for k, v in T.param_specs(cfg, axis.tp).items() if k != "layers"},
             layers=T.param_specs(cfg, axis.tp)["layers"])
-        zeros = grad_zeros(storage, sspecs)
+        with phase("bwd"):
+            zeros = grad_zeros(storage, sspecs)
         grads, (nlls, auxs) = lax.scan(body, zeros, batch)
         if not acc.partitioned:
             outer_grads, layer_grads = split_tree(grads)
@@ -283,6 +294,11 @@ def make_grad_fn(cfg: ModelConfig, axis: AxisCtx, acc: AccumConfig,
     # ------------------------------------------------------------------
     # layered (layer-major) gradient accumulation — the paper's §3
     # ------------------------------------------------------------------
+    # Phases: "fwd" but for the reverse layer scan and the embed backward
+    # ("bwd": jax.vjp's recomputed forward reads jvp(...) and its backward
+    # transpose(jvp(...)) in op_name), the head's gradient sums ("bwd") and
+    # the ZeRO adapters.
+    @phase("fwd")
     def layered_grad(storage, batch, opt_layers=None):
         inv_n = 1.0 / n_global_tokens(batch)
         aux_scale = 1.0 / (M * cfg.num_layers * dp_total())
@@ -368,14 +384,18 @@ def make_grad_fn(cfg: ModelConfig, axis: AxisCtx, acc: AccumConfig,
             dfn_a, dhead_a, demb_a = acc2
             add = lambda a, b: jax.tree.map(
                 lambda u, v: u + v.astype(jnp.float32), a, b)
-            return (add(dfn_a, dfn),
-                    add(dhead_a, dhead) if dhead is not None else None,
-                    add(demb_a, demb)), (dx.astype(jnp.dtype(cfg.dtype)), nll)
+            with phase("bwd"):
+                return (add(dfn_a, dfn),
+                        add(dhead_a, dhead) if dhead is not None else None,
+                        add(demb_a, demb)), (dx.astype(jnp.dtype(cfg.dtype)),
+                                             nll)
 
-        head_acc0 = (grad_zeros(outer_g["final_norm"], outer_specs["final_norm"]),
-                     None if tied else grad_zeros(outer_g["head"],
-                                                  outer_specs["head"]),
-                     grad_zeros(outer_g["embed"], outer_specs["embed"]))
+        with phase("bwd"):
+            head_acc0 = (
+                grad_zeros(outer_g["final_norm"], outer_specs["final_norm"]),
+                None if tied else grad_zeros(outer_g["head"],
+                                             outer_specs["head"]),
+                grad_zeros(outer_g["embed"], outer_specs["embed"]))
         (dfn, dhead, demb), (dX, nlls) = lax.scan(head_body, head_acc0,
                                                   (batch, xL))
 
@@ -423,16 +443,17 @@ def make_grad_fn(cfg: ModelConfig, axis: AxisCtx, acc: AccumConfig,
                 return (dx_prev, dshared_acc), (new_p, new_mu, new_nu)
             return (dx_prev, dshared_acc), dw_store
 
-        shared_zero = grad_zeros(shared_g, outer_specs.get("shared", {}))
-        if layer_update is not None:
-            mu_l, nu_l = opt_layers
-            (dX0, dshared), (new_layers, new_mu, new_nu) = lax.scan(
-                bwd_layer, (dX, shared_zero),
-                (layers_s, windows, flags, CKPT, mu_l, nu_l), reverse=True)
-        else:
-            (dX0, dshared), layer_grads = lax.scan(
-                bwd_layer, (dX, shared_zero),
-                (layers_s, windows, flags, CKPT), reverse=True)
+        with phase("bwd"):
+            shared_zero = grad_zeros(shared_g, outer_specs.get("shared", {}))
+            if layer_update is not None:
+                mu_l, nu_l = opt_layers
+                (dX0, dshared), (new_layers, new_mu, new_nu) = lax.scan(
+                    bwd_layer, (dX, shared_zero),
+                    (layers_s, windows, flags, CKPT, mu_l, nu_l), reverse=True)
+            else:
+                (dX0, dshared), layer_grads = lax.scan(
+                    bwd_layer, (dX, shared_zero),
+                    (layers_s, windows, flags, CKPT), reverse=True)
 
         # ---- embed backward -------------------------------------------------
         def emb_body(demb_acc, xs):
@@ -447,7 +468,8 @@ def make_grad_fn(cfg: ModelConfig, axis: AxisCtx, acc: AccumConfig,
             return jax.tree.map(lambda u, v: u + v.astype(jnp.float32),
                                 demb_acc, de), None
 
-        demb, _ = lax.scan(emb_body, demb, (batch, dX0))
+        with phase("bwd"):
+            demb, _ = lax.scan(emb_body, demb, (batch, dX0))
 
         outer_grads = {"embed": demb, "final_norm": dfn, "shared": dshared}
         if dhead is not None:

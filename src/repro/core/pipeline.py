@@ -66,6 +66,7 @@ from repro.compat import pvary_missing
 from repro.core.schedules import PipeSpec
 from repro.models import transformer as T
 from repro.models.common import AxisCtx, ModelConfig, apply_norm
+from repro.obs.trace import phase
 
 PyTree = Any
 
@@ -377,6 +378,7 @@ def _make_tick_grad_fn(cfg: ModelConfig, axis: AxisCtx, spec: PipeSpec,
             lambda p: pvary_missing(p[0].astype(dtype), dp_axes),
             params["layers"])
 
+    @phase("zero_gather")
     def gather_chunk(params, v2):
         """all_gather local chunk v2's weights over `data`: leaves
         [k_c, 1, 1, chunk] -> [k_c, *model-local shape] bf16.  One
@@ -623,6 +625,7 @@ def _make_tick_grad_fn(cfg: ModelConfig, axis: AxisCtx, spec: PipeSpec,
             outer_grads["head"] = dhead
         outer_grads = {k: v for k, v in outer_grads.items()
                        if k in outer_store}
+        @phase("zero_reduce")
         def reduce_outer(g):
             # outer leaves are stage-replicated but their partials live on
             # the stages that used them (loss stage for embed/head/norm,
@@ -640,6 +643,7 @@ def _make_tick_grad_fn(cfg: ModelConfig, axis: AxisCtx, spec: PipeSpec,
                        for k, v in outer_grads.items()}
 
         if partitioned:
+            @phase("zero_reduce")
             def scatter_leaf(Wl):
                 """Per-chunk reduce-scatter over `data`: V psum_scatters per
                 leaf per pass, the explicit transpose of gather_chunk."""
@@ -650,6 +654,7 @@ def _make_tick_grad_fn(cfg: ModelConfig, axis: AxisCtx, spec: PipeSpec,
                 return jnp.concatenate(parts, axis=0)[None]
             layer_grads = jax.tree.map(scatter_leaf, dW)
         else:
+            @phase("zero_reduce")
             def reduce_layer(g):
                 if axis.data:
                     g = lax.psum(g, axis.data)

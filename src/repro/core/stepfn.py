@@ -21,6 +21,7 @@ from repro.core import partition as zp
 from repro.core.accumulation import AccumConfig, make_grad_fn, split_tree
 from repro.models import transformer as T
 from repro.models.common import AxisCtx, ModelConfig
+from repro.obs.trace import phase
 from repro.optim.adam import AdamConfig, adam_init, adam_step
 
 PyTree = Any
@@ -625,12 +626,14 @@ def build_fused_train_step(cfg: ModelConfig, mesh: Mesh, acc: AccumConfig,
     c = opt_cfg
 
     def step(storage, opt, batch):
-        stp = opt["step"] + 1
-        lr = schedule(c, stp)
-        b1c = 1 - c.b1 ** stp.astype(jnp.float32)
-        b2c = 1 - c.b2 ** stp.astype(jnp.float32)
+        with phase("optimizer"):
+            stp = opt["step"] + 1
+            lr = schedule(c, stp)
+            b1c = 1 - c.b1 ** stp.astype(jnp.float32)
+            b2c = 1 - c.b2 ** stp.astype(jnp.float32)
         mdt = jnp.dtype(c.moment_dtype)
 
+        @phase("optimizer")
         def upd(p, m, v, g):
             g = g.astype(jnp.float32)
             if c.grad_clip > 0:   # per-leaf clip (global norm unavailable)

@@ -1,4 +1,10 @@
-"""Span tracing with a Chrome-trace (Perfetto-loadable) exporter.
+"""Span tracing with a Chrome-trace (Perfetto-loadable) exporter, and the
+train step's phase scopes.
+
+``phase(name)`` names one of ``PHASES`` inside a jitted program: every HLO
+instruction traced under it carries the name in its ``op_name`` metadata
+(fusions and Pallas custom-calls included), so a device trace's ops can be
+told apart by phase.  It changes metadata only, never the computation.
 
 ``Tracer`` records complete events (``ph: "X"``) under (pid, tid) lanes and
 serializes the standard ``{"traceEvents": [...]}`` JSON object form, which
@@ -24,9 +30,33 @@ import time
 
 _KIND_NAMES = {0: None, 1: "F", 2: "B", 3: "Bd", 4: "Bw"}
 
+# The train step's phases, innermost scope wins: the forward ("fwd"), the
+# backward with its recomputed forward ("bwd"), the ZeRO weight gather and
+# gradient reduction, and the optimizer update.  Under ``jax.vjp`` the
+# recomputed forward reads ``jvp(...)`` in ``op_name`` and the backward
+# ``transpose(jvp(...))``.
+PHASES = ("fwd", "bwd", "zero_gather", "zero_reduce", "optimizer")
+
+
+def phase(name: str):
+    """``jax.named_scope(name)`` for one of ``PHASES``: a context manager, or
+    a decorator that opens a scope of its own on every call."""
+    assert name in PHASES, (name, PHASES)
+    return _named_scope(name)
+
+
+@contextlib.contextmanager
+def _named_scope(name: str):
+    import jax
+    with jax.named_scope(name):
+        yield
+
 
 class Tracer:
-    """Collects Chrome-trace events; wall clock in µs from construction."""
+    """Collects Chrome-trace events; wall clock in µs from construction.
+
+    ``span`` also opens a ``jax.profiler.TraceAnnotation`` of the same name,
+    so under a profiler capture the span sits on the device trace's clock."""
 
     def __init__(self, *, clock=time.perf_counter):
         self._clock = clock
@@ -58,9 +88,11 @@ class Tracer:
     @contextlib.contextmanager
     def span(self, name: str, *, cat: str = "phase", pid: int = 0,
              tid: int = 0, **args):
+        import jax
         t0 = self.now_us()
         try:
-            yield self
+            with jax.profiler.TraceAnnotation(name):
+                yield self
         finally:
             self.complete(name, ts_us=t0, dur_us=self.now_us() - t0, cat=cat,
                           pid=pid, tid=tid, args=args or None)

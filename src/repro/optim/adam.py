@@ -18,6 +18,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro.obs.trace import phase
+
 PyTree = Any
 
 
@@ -62,6 +64,7 @@ def adam_init(storage: PyTree, *, moment_dtype="float32") -> PyTree:
             "step": jnp.zeros((), jnp.int32, device=step_at)}
 
 
+@phase("optimizer")
 def adam_step(c: AdamConfig, storage: PyTree, opt: PyTree, grads: PyTree, *,
               sq_reduce: Callable[[PyTree], jnp.ndarray] | None = None,
               fused: bool | Callable = False) -> tuple[PyTree, PyTree, dict]:

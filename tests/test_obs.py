@@ -188,6 +188,39 @@ def test_chrome_trace_schema_roundtrip(tmp_path):
         assert got[key] == pytest.approx(want[key], abs=1e-9)
 
 
+def test_tracer_span_sits_on_the_profiler_clock(tmp_path):
+    """A ``Tracer.span`` is also a profiler annotation: under a capture it
+    lands on a host plane of the device trace, around the work it spans."""
+    import glob
+    from jax.profiler import ProfileData
+    tracer = obs_trace.Tracer()
+    with jax.profiler.trace(str(tmp_path)):
+        with tracer.span("build_step"):
+            jax.block_until_ready(jnp.ones(8) * 2)
+    assert [e["name"] for e in tracer.events] == ["build_step"]
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    found = [e.duration_ns for p in ProfileData.from_file(path).planes
+             if p.name.startswith("/host:") for line in p.lines
+             for e in line.events if e.name == "build_step"]
+    assert len(found) == 1 and found[0] > 0
+
+
+def test_phase_scopes_only_known_names():
+    assert obs_trace.PHASES == ("fwd", "bwd", "zero_gather", "zero_reduce",
+                                "optimizer")
+    with pytest.raises(AssertionError):
+        obs_trace.phase("backward")
+
+    @jax.jit
+    @obs_trace.phase("optimizer")
+    def f(x):
+        with obs_trace.phase("fwd"):
+            return jnp.sin(x) * 2
+
+    text = f.lower(jnp.ones(4)).as_text(debug_info=True)
+    assert "optimizer/fwd/sin" in text
+
+
 def test_validate_chrome_rejects_malformed():
     assert obs_trace.validate_chrome([]) != []
     assert obs_trace.validate_chrome({"traceEvents": "nope"}) != []
