@@ -2,18 +2,33 @@
 logit-softcap support — forward AND backward.
 
 TPU adaptation of the (GPU-origin) flash algorithm:
-  * tiling is chosen for the MXU and VMEM, not for SM shared memory: the
-    query tile is ``(block_q, head_dim)`` with block_q a multiple of the
-    128-lane register layout, and head_dim padded to 128 lanes by the caller;
   * one grid step owns a whole (batch, head, q-block); K/V for that head are
     staged into VMEM once per grid step via their BlockSpec and the k-loop
     walks VMEM tiles — HBM→VMEM traffic is O(S·D) per head rather than
-    O(S²), which is the flash insight restated for the TPU memory hierarchy;
-  * the running (max, sum) softmax rescaling is carried in fp32 vector
-    registers; matmuls hit the MXU via ``jnp.dot`` on (block_q, D)x(D,
-    block_k) tiles;
-  * causal + window masking prunes blocks *in the grid* (no wasted MXU work
-    on fully-masked tiles): loop bounds are derived from the block index.
+    O(S²), which is the flash insight restated for the TPU memory hierarchy.
+    Only S is padded (by ``ops.flash_attention``, to a block multiple);
+    head_dim is whatever the model has, and VMEM pads it to 128 lanes;
+  * q, k, v and dO are upcast to fp32 as they are loaded, the softmax scale
+    is folded into the resident q (or, in dkv, k) once per grid step, and
+    every product is fp32 x fp32 into fp32 through ``lax.dot_general``
+    dimension numbers that contract the right axes (q·kᵀ, dO·vᵀ, pᵀ·dO,
+    dSᵀ·q): no tile is transposed in the loop.  (bf16 operands, with p and
+    dS cast for their products, measured slower on a v5e: the loop is bound
+    by its vector work, not the MXU);
+  * causal + window masking prunes blocks *in the grid* (no MXU work on
+    fully-masked tiles): loop bounds are derived from the block index.  Of
+    the tiles walked, only those that touch an edge — the diagonal, the
+    window's lower edge, the padded ``kv_len`` tail — build the mask and
+    select; the band's interior runs a second, mask-free loop body
+    (``_walk``: three ``fori_loop``s over [lo, a), [a, b), [b, hi));
+  * tiles are sized from the shape: ``tile_plan(S, head_dim, dtype, rep)``
+    picks (block_q, block_k) up to 512 keys by 512 queries, as large as the
+    padding of S and the default 16 MiB of scoped VMEM allow (the dkv
+    kernel's whole-S staging of q, dO and the lse/delta columns of its
+    ``rep`` query heads is the largest claim); ``tile_counts`` gives the
+    masked and mask-free tiles a head walks.  ``block_sizes`` is the one
+    place a call's sizes are decided: the plan where none is given, else
+    the caller's, which win.
 
 Backward (the custom-VJP contract, exposed via kernels/ops.py):
   * the forward additionally emits the per-row log-sum-exp ``lse = m +
@@ -44,6 +59,144 @@ from jax.experimental import pallas as pl
 from repro import compat
 
 NEG_INF = -1.0e38
+VMEM_LIMIT = 16 * 2**20        # a Pallas kernel's default scoped VMEM
+_NT = (((1,), (1,)), ((), ()))  # a · bᵀ
+_TN = (((0,), (0,)), ((), ()))  # aᵀ · b
+_NN = (((1,), (0,)), ((), ()))  # a · b
+
+
+def _dot(a, b, dims):
+    return jax.lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
+
+
+def _min(a, b):
+    return min(a, b) if isinstance(a, int) and isinstance(b, int) \
+        else jnp.minimum(a, b)
+
+
+def _max(a, b):
+    return max(a, b) if isinstance(a, int) and isinstance(b, int) \
+        else jnp.maximum(a, b)
+
+
+def _cdiv(a, b):
+    return -(-a // b)
+
+
+def _split(lo, hi, a, b):
+    """[lo, hi) cut at the interior [a, b), clamped so lo <= a <= b <= hi."""
+    a = _min(_max(a, lo), hi)
+    return lo, a, _min(_max(b, a), hi), hi
+
+
+def _walk(ranges, body, carry):
+    """``body(masked)``'s loop over the edge tiles [lo, a) and [b, hi) and
+    ``body(False)``'s over the interior [a, b), in order."""
+    lo, a, b, hi = ranges
+    carry = jax.lax.fori_loop(lo, a, body(True), carry)
+    carry = jax.lax.fori_loop(a, b, body(False), carry)
+    return jax.lax.fori_loop(b, hi, body(True), carry)
+
+
+def _k_ranges(qi, *, block_q, block_k, kv_len, causal, window):
+    """(lo, a, b, hi): the k-blocks q-block ``qi`` walks, [lo, hi), and
+    within them the interior [a, b) whose tiles every row sees whole."""
+    q0 = qi * block_q
+    q1 = q0 + block_q - 1
+    n_k = _cdiv(kv_len, block_k)                      # valid k-blocks only
+    # highest k-block that any row of this q-block can see
+    hi = _min(q1 // block_k + 1, n_k) if causal else n_k
+    lo = _max((q0 - window + 1) // block_k, 0) if window > 0 else 0
+    b = kv_len // block_k                             # unpadded k-blocks
+    if causal:                                        # last key <= first row
+        b = _min(b, (q0 + 1) // block_k)
+    # window: the last row still sees the tile's first key
+    a = _cdiv(q1 - window + 1, block_k) if window > 0 else lo
+    return _split(lo, hi, a, b)
+
+
+def _q_ranges(kb, *, block_q, block_k, seq_len, kv_len, causal, window):
+    """(lo, a, b, hi): the q-blocks k-block ``kb`` is seen from, [lo, hi),
+    and within them the interior [a, b) whose rows see the tile whole."""
+    k0 = kb * block_k
+    k1 = k0 + block_k - 1
+    n_q = seq_len // block_q
+    lo = k0 // block_q if causal else 0
+    # largest q any row of this k-block reaches: k1 + window - 1
+    hi = _min((k1 + window - 1) // block_q + 1, n_q) if window > 0 else n_q
+    a = _cdiv(k1, block_q) if causal else lo          # first row >= last key
+    b = (k0 + window) // block_q if window > 0 else hi
+    if kv_len < seq_len:
+        # a tile with padded keys is masked; one of padding alone is skipped
+        hi = jnp.where(k0 >= kv_len, lo, hi)
+        b = jnp.where(k1 >= kv_len, lo, b)
+    return _split(lo, hi, a, b)
+
+
+def tile_counts(seq_len: int, block_q: int, block_k: int, *,
+                causal: bool = True, window: int = 0,
+                kv_len: int = 0) -> tuple[int, int]:
+    """(masked, mask-free) tiles one head walks in a call of each kernel, for
+    the padded ``seq_len`` and the true ``kv_len`` (0 = seq_len)."""
+    kv_len = kv_len or seq_len
+    masked = free = 0
+    for qi in range(seq_len // block_q):
+        lo, a, b, hi = _k_ranges(qi, block_q=block_q, block_k=block_k,
+                                 kv_len=kv_len, causal=causal, window=window)
+        masked += (a - lo) + (hi - b)
+        free += b - a
+    return masked, free
+
+
+def _cover(n):
+    """The power of two that covers ``n`` rows, at least 16."""
+    return max(16, 1 << (n - 1).bit_length())
+
+
+def tile_plan(seq_len: int, head_dim: int, dtype,
+              rep: int = 1) -> tuple[int, int]:
+    """(block_q, block_k) for sequences of ``seq_len`` (before padding) at
+    ``head_dim`` in ``dtype``, ``rep`` query heads to a KV head.
+
+    A sequence of at most 128 is one tile, the power of two that covers it
+    (at least 16 rows).  Longer ones take the largest tiles of 128, 256 or
+    512 that pad S no further than 128-row tiles would and whose dkv kernel
+    fits ``VMEM_LIMIT``.
+    """
+    if seq_len <= 128:
+        return _cover(seq_len), _cover(seq_len)
+    S = _cdiv(seq_len, 128) * 128
+    itemsize = jnp.dtype(dtype).itemsize
+    for bk in (512, 256):
+        for bq in (512, 256):
+            if S % bk or S % bq:
+                continue
+            if _dkv_vmem(S, head_dim, itemsize, rep, bq, bk) <= VMEM_LIMIT:
+                return bq, bk
+    return 128, 128
+
+
+def _dkv_vmem(S, D, itemsize, rep, bq, bk):
+    """Scoped VMEM of the dkv kernel, the largest of the three, in bytes,
+    estimated from above: the ``rep`` heads' whole-S q and dO (lane-padded
+    to 128) and lse/delta columns (128 bytes a row), double-buffered, and
+    eight fp32 [block_q, block_k] tiles for the loop body.  Checked against
+    the v5e compiler: X_32's backward at S = 8192 is refused at 512 x 512
+    and compiles at the planned 256 x 512; 32 q / 8 kv heads of 128 at S =
+    2048 compile at the planned 256 x 512."""
+    lanes = _cdiv(D, 128) * 128
+    return 2 * 2 * rep * S * (lanes * itemsize + 128) + 8 * bq * bk * 4
+
+
+def block_sizes(seq_len: int, head_dim: int, dtype, rep: int = 1,
+                block_q: int | None = None,
+                block_k: int | None = None) -> tuple[int, int]:
+    """(block_q, block_k) of a call: ``tile_plan``'s where None, else the
+    caller's, cut to the power of two that covers the sequence."""
+    plan_q, plan_k = tile_plan(seq_len, head_dim, dtype, rep)
+    cut = _cover(seq_len)
+    return (min(block_q, cut) if block_q else plan_q,
+            min(block_k, cut) if block_k else plan_k)
 
 
 def _block_mask(q_pos, k_pos, *, causal: bool, window: int, kv_len: int,
@@ -61,70 +214,73 @@ def _block_mask(q_pos, k_pos, *, causal: bool, window: int, kv_len: int,
     return mask
 
 
+def _scores(q, k, *, softcap):
+    """fp32 (softcapped) logits of one tile, the scale already in q or k.
+    Returns (s, tanh term or None)."""
+    s = _dot(q, k, _NT)
+    if softcap > 0:
+        t = jnp.tanh(s / softcap)
+        return softcap * t, t
+    return s, None
+
+
+def _f32(x):
+    return x.astype(jnp.float32)
+
+
 def _attn_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale: float,
                      block_q: int, block_k: int, seq_len: int, kv_len: int,
                      causal: bool, window: int, softcap: float):
     qi = pl.program_id(2)
-    q = q_ref[0, 0].astype(jnp.float32) * scale       # [block_q, D]
+    q = _f32(q_ref[0, 0]) * scale                     # [block_q, D]
     D = q.shape[-1]
     q_pos = qi * block_q + jax.lax.iota(jnp.int32, block_q)
+    mask_of = functools.partial(_block_mask, causal=causal, window=window,
+                                kv_len=kv_len, seq_len=seq_len)
 
-    n_k = (kv_len + block_k - 1) // block_k           # valid k-blocks only
-    if causal:
-        # highest k-block that any row of this q-block can see
-        hi = (qi * block_q + block_q - 1) // block_k + 1
-        hi = min(hi, n_k) if isinstance(hi, int) else jnp.minimum(hi, n_k)
-    else:
-        hi = n_k
-    if window > 0:
-        lo = jnp.maximum((qi * block_q - window + 1) // block_k, 0)
-    else:
-        lo = 0
-
-    def body(kb, carry):
-        acc, m_prev, l_prev = carry
-        k = k_ref[0, 0, pl.ds(kb * block_k, block_k)].astype(jnp.float32)
-        v = v_ref[0, 0, pl.ds(kb * block_k, block_k)].astype(jnp.float32)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)  # [bq, bk]
-        if softcap > 0:
-            s = softcap * jnp.tanh(s / softcap)
-        k_pos = kb * block_k + jax.lax.iota(jnp.int32, block_k)
-        mask = _block_mask(q_pos, k_pos, causal=causal, window=window,
-                           kv_len=kv_len, seq_len=seq_len)
-        s = jnp.where(mask, s, NEG_INF)
-        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_cur)
-        p = jnp.exp(s - m_cur)
-        l_cur = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc = acc * alpha + jnp.dot(p, v, preferred_element_type=jnp.float32)
-        return acc, m_cur, l_cur
+    def body(masked):
+        def step(kb, carry):
+            acc, m_prev, l_prev = carry
+            k = _f32(k_ref[0, 0, pl.ds(kb * block_k, block_k)])
+            v = _f32(v_ref[0, 0, pl.ds(kb * block_k, block_k)])
+            s, _ = _scores(q, k, softcap=softcap)     # [bq, bk]
+            if masked:
+                k_pos = kb * block_k + jax.lax.iota(jnp.int32, block_k)
+                s = jnp.where(mask_of(q_pos, k_pos), s, NEG_INF)
+            m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_cur)
+            p = jnp.exp(s - m_cur)
+            l_cur = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            acc = acc * alpha + _dot(p, v, _NN)
+            return acc, m_cur, l_cur
+        return step
 
     # the running (max, sum) are [block_q, 1] columns: one value per row, in
     # the sublane orientation the row reductions produce
-    acc0 = jnp.zeros((block_q, D), jnp.float32)
-    m0 = jnp.full((block_q, 1), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((block_q, 1), jnp.float32)
-    acc, m, l = jax.lax.fori_loop(lo, hi, body, (acc0, m0, l0))
+    carry = (jnp.zeros((block_q, D), jnp.float32),
+             jnp.full((block_q, 1), NEG_INF, jnp.float32),
+             jnp.zeros((block_q, 1), jnp.float32))
+    acc, m, l = _walk(_k_ranges(qi, block_q=block_q, block_k=block_k,
+                                kv_len=kv_len, causal=causal, window=window),
+                      body, carry)
     l = jnp.where(l == 0.0, 1.0, l)                    # fully-masked rows
     o_ref[0, 0] = (acc / l).astype(o_ref.dtype)
     lse_ref[0, 0] = m + jnp.log(l)
 
 
-def _recompute_p(q, k, lse, q_pos, k_pos, *, scale, causal, window, kv_len,
+def _recompute_p(q, k, lse, q_pos, k_pos, *, masked, causal, window, kv_len,
                  seq_len, softcap):
     """(p, softcap tanh term) for one tile, from the raw logits and the
     ``[block_q, 1]`` lse column.
 
     Rows whose forward was fully masked carry ``lse = NEG_INF`` (they only
-    exist in the pad region); their probabilities are forced to zero rather
-    than letting ``exp(s - NEG_INF)`` overflow.
+    exist in the pad region, and only in tiles that touch an edge); their
+    probabilities are forced to zero rather than letting ``exp(s -
+    NEG_INF)`` overflow.
     """
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-    if softcap > 0:
-        t = jnp.tanh(s / softcap)
-        s = softcap * t
-    else:
-        t = None
+    s, t = _scores(q, k, softcap=softcap)
+    if not masked:
+        return jnp.exp(s - lse), t
     mask = _block_mask(q_pos, k_pos, causal=causal, window=window,
                        kv_len=kv_len, seq_len=seq_len)
     dead = lse <= 0.5 * NEG_INF
@@ -138,36 +294,30 @@ def _attn_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                         seq_len: int, kv_len: int, causal: bool, window: int,
                         softcap: float):
     qi = pl.program_id(2)
-    q = q_ref[0, 0].astype(jnp.float32)               # [block_q, D]
-    do = do_ref[0, 0].astype(jnp.float32)
+    q = _f32(q_ref[0, 0]) * scale                     # [block_q, D]
+    do = _f32(do_ref[0, 0])
     lse = lse_ref[0, 0]                               # [block_q, 1] fp32
     delta = delta_ref[0, 0]                           # [block_q, 1] fp32
     D = q.shape[-1]
     q_pos = qi * block_q + jax.lax.iota(jnp.int32, block_q)
 
-    n_k = (kv_len + block_k - 1) // block_k
-    if causal:
-        hi = (qi * block_q + block_q - 1) // block_k + 1
-        hi = min(hi, n_k) if isinstance(hi, int) else jnp.minimum(hi, n_k)
-    else:
-        hi = n_k
-    lo = jnp.maximum((qi * block_q - window + 1) // block_k, 0) if window > 0 \
-        else 0
+    def body(masked):
+        def step(kb, acc):
+            k = _f32(k_ref[0, 0, pl.ds(kb * block_k, block_k)])
+            v = _f32(v_ref[0, 0, pl.ds(kb * block_k, block_k)])
+            k_pos = kb * block_k + jax.lax.iota(jnp.int32, block_k)
+            p, t = _recompute_p(q, k, lse, q_pos, k_pos, masked=masked,
+                                causal=causal, window=window, kv_len=kv_len,
+                                seq_len=seq_len, softcap=softcap)
+            ds = p * (_dot(do, v, _NT) - delta)
+            if softcap > 0:
+                ds = ds * (1.0 - t * t)               # tanh chain rule
+            return acc + _dot(ds, k, _NN)
+        return step
 
-    def body(kb, acc):
-        k = k_ref[0, 0, pl.ds(kb * block_k, block_k)].astype(jnp.float32)
-        v = v_ref[0, 0, pl.ds(kb * block_k, block_k)].astype(jnp.float32)
-        k_pos = kb * block_k + jax.lax.iota(jnp.int32, block_k)
-        p, t = _recompute_p(q, k, lse, q_pos, k_pos, scale=scale,
-                            causal=causal, window=window, kv_len=kv_len,
-                            seq_len=seq_len, softcap=softcap)
-        dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - delta)
-        if softcap > 0:
-            ds = ds * (1.0 - t * t)                   # tanh chain rule
-        return acc + jnp.dot(ds, k, preferred_element_type=jnp.float32)
-
-    acc = jax.lax.fori_loop(lo, hi, body, jnp.zeros((block_q, D), jnp.float32))
+    acc = _walk(_k_ranges(qi, block_q=block_q, block_k=block_k,
+                          kv_len=kv_len, causal=causal, window=window),
+                body, jnp.zeros((block_q, D), jnp.float32))
     dq_ref[0, 0] = (acc * scale).astype(dq_ref.dtype)
 
 
@@ -176,42 +326,39 @@ def _attn_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                          block_k: int, seq_len: int, kv_len: int, causal: bool,
                          window: int, softcap: float, rep: int):
     kb = pl.program_id(2)
-    k = k_ref[0, 0].astype(jnp.float32)               # [block_k, D]
-    v = v_ref[0, 0].astype(jnp.float32)
+    k = _f32(k_ref[0, 0]) * scale                     # scores only: q·(k/√D)ᵀ
+    v = _f32(v_ref[0, 0])
     D = k.shape[-1]
     k_pos = kb * block_k + jax.lax.iota(jnp.int32, block_k)
+    ranges = _q_ranges(kb, block_q=block_q, block_k=block_k, seq_len=seq_len,
+                       kv_len=kv_len, causal=causal, window=window)
 
-    n_q = seq_len // block_q
-    lo = (kb * block_k) // block_q if causal else 0
-    if window > 0:
-        # largest q any row of this k-block reaches: k_max + window - 1
-        hi = jnp.minimum((kb * block_k + block_k + window - 2) // block_q + 1,
-                         n_q)
-    else:
-        hi = n_q
-
-    dk = jnp.zeros((block_k, D), jnp.float32)
-    dv = jnp.zeros((block_k, D), jnp.float32)
+    carry = (jnp.zeros((block_k, D), jnp.float32),
+             jnp.zeros((block_k, D), jnp.float32))
     for r in range(rep):                               # GQA: replicated q heads
-        def body(qb, carry):
-            dk, dv = carry
-            q = q_ref[0, 0, r, pl.ds(qb * block_q, block_q)].astype(jnp.float32)
-            do = do_ref[0, 0, r, pl.ds(qb * block_q, block_q)].astype(jnp.float32)
-            lse = lse_ref[0, 0, r, pl.ds(qb * block_q, block_q)]
-            delta = delta_ref[0, 0, r, pl.ds(qb * block_q, block_q)]
-            q_pos = qb * block_q + jax.lax.iota(jnp.int32, block_q)
-            p, t = _recompute_p(q, k, lse, q_pos, k_pos, scale=scale,
-                                causal=causal, window=window, kv_len=kv_len,
-                                seq_len=seq_len, softcap=softcap)
-            dv = dv + jnp.dot(p.T, do, preferred_element_type=jnp.float32)
-            dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-            ds = p * (dp - delta)
-            if softcap > 0:
-                ds = ds * (1.0 - t * t)
-            dk = dk + jnp.dot(ds.T, q, preferred_element_type=jnp.float32)
-            return dk, dv
+        def body(masked, r=r):
+            def step(qb, carry):
+                dk, dv = carry
+                rows = pl.ds(qb * block_q, block_q)
+                q = _f32(q_ref[0, 0, r, rows])
+                do = _f32(do_ref[0, 0, r, rows])
+                lse = lse_ref[0, 0, r, rows]
+                delta = delta_ref[0, 0, r, rows]
+                q_pos = qb * block_q + jax.lax.iota(jnp.int32, block_q)
+                p, t = _recompute_p(q, k, lse, q_pos, k_pos, masked=masked,
+                                    causal=causal, window=window,
+                                    kv_len=kv_len, seq_len=seq_len,
+                                    softcap=softcap)
+                dv = dv + _dot(p, do, _TN)
+                ds = p * (_dot(do, v, _NT) - delta)
+                if softcap > 0:
+                    ds = ds * (1.0 - t * t)
+                dk = dk + _dot(ds, q, _TN)
+                return dk, dv
+            return step
 
-        dk, dv = jax.lax.fori_loop(lo, hi, body, (dk, dv))
+        carry = _walk(ranges, body, carry)
+    dk, dv = carry
     dk_ref[0, 0] = (dk * scale).astype(dk_ref.dtype)
     dv_ref[0, 0] = dv.astype(dv_ref.dtype)
 
@@ -223,23 +370,23 @@ _STATICS = ("causal", "window", "softcap", "kv_len", "block_q", "block_k",
 @functools.partial(jax.jit, static_argnames=_STATICS)
 def flash_attention_fwd(q, k, v, *, causal: bool = True, window: int = 0,
                         softcap: float = 0.0, kv_len: int = 0,
-                        block_q: int = 128, block_k: int = 128,
+                        block_q: int | None = None,
+                        block_k: int | None = None,
                         interpret: bool = False):
     """q: [B, S, Hq, D]; k, v: [B, S, Hkv, D] -> (out [B, S, Hq, D],
     lse [B, Hq, S, 1] fp32).
 
     GQA is handled by head-index mapping in the BlockSpec (no KV materialised
-    repeat).  S must be a multiple of the block sizes (the ops wrapper pads);
-    ``kv_len`` (0 = S) is the true pre-pad length — padded key rows are
-    masked in-kernel.
+    repeat).  S must be a multiple of the block sizes (the ops wrapper pads;
+    None takes ``tile_plan``'s); ``kv_len`` (0 = S) is the true pre-pad
+    length — padded key rows are masked in-kernel.
     """
     B, S, Hq, D = q.shape
     Hkv = k.shape[2]
     rep = Hq // Hkv
     scale = D ** -0.5
     kv_len = kv_len or S
-    block_q = min(block_q, S)
-    block_k = min(block_k, S)
+    block_q, block_k = block_sizes(S, D, q.dtype, rep, block_q, block_k)
     assert S % block_q == 0 and S % block_k == 0, (S, block_q, block_k)
     # the per-row lse is stored as a [S, 1] column: its blocks are
     # (block_q, 1), whose last dim is the array's own, which the TPU's
@@ -274,8 +421,9 @@ def flash_attention_fwd(q, k, v, *, causal: bool = True, window: int = 0,
 
 
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
-                    softcap: float = 0.0, kv_len: int = 0, block_q: int = 128,
-                    block_k: int = 128, interpret: bool = False):
+                    softcap: float = 0.0, kv_len: int = 0,
+                    block_q: int | None = None, block_k: int | None = None,
+                    interpret: bool = False):
     """Forward only (back-compat entry; the lse residual is discarded)."""
     out, _ = flash_attention_fwd(q, k, v, causal=causal, window=window,
                                  softcap=softcap, kv_len=kv_len,
@@ -287,7 +435,8 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
 @functools.partial(jax.jit, static_argnames=_STATICS)
 def flash_attention_bwd(q, k, v, out, lse, do, *, causal: bool = True,
                         window: int = 0, softcap: float = 0.0, kv_len: int = 0,
-                        block_q: int = 128, block_k: int = 128,
+                        block_q: int | None = None,
+                        block_k: int | None = None,
                         interpret: bool = False):
     """(dq, dk, dv) by re-walking K/V (resp. Q) tiles — no O(S²) intermediate.
 
@@ -299,8 +448,8 @@ def flash_attention_bwd(q, k, v, out, lse, do, *, causal: bool = True,
     rep = Hq // Hkv
     scale = D ** -0.5
     kv_len = kv_len or S
-    block_q = min(block_q, S)
-    block_k = min(block_k, S)
+    block_q, block_k = block_sizes(S, D, q.dtype, rep, block_q, block_k)
+    assert S % block_q == 0 and S % block_k == 0, (S, block_q, block_k)
 
     qt = q.transpose(0, 2, 1, 3)                       # [B, Hq, S, D]
     kt = k.transpose(0, 2, 1, 3)                       # [B, Hkv, S, D]

@@ -73,18 +73,20 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
-                    softcap: float = 0.0, block_q: int = 128,
-                    block_k: int = 128, interpret: bool | None = None):
+                    softcap: float = 0.0, block_q: int | None = None,
+                    block_k: int | None = None, interpret: bool | None = None):
     """q: [B, S, Hq, D]; k, v: [B, S, Hkv, D] -> [B, S, Hq, D].
 
-    Differentiable (custom VJP; flash-style recomputing backward).  Pads S
-    up to a common block multiple; padded key rows are masked in-kernel via
-    the true ``kv_len`` (not just causality), padded query rows are dropped.
+    Differentiable (custom VJP; flash-style recomputing backward).  Block
+    sizes left None are ``tile_plan``'s for the shape.  Pads S up to a
+    common block multiple; padded key rows are masked in-kernel via the true
+    ``kv_len`` (not just causality), padded query rows are dropped.
     """
     if interpret is None:
         interpret = _interpret_default()
     B, S, Hq, D = q.shape
-    bq, bk = min(block_q, max(S, 16)), min(block_k, max(S, 16))
+    bq, bk = _fa.block_sizes(S, D, q.dtype, Hq // k.shape[2], block_q,
+                             block_k)
     mult = bq * bk // math.gcd(bq, bk)     # lcm: must divide both block sizes
     pad = (-S) % mult
     if pad:

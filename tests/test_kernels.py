@@ -115,6 +115,167 @@ def test_flash_attention_grads_bf16():
                                    rtol=2e-2, atol=2e-2, err_msg=f"d{name}")
 
 
+# ---------------------------------------------------------------------------
+# The planned tiles (no block size given): several k-tiles, edge and interior
+# tiles, padded kv_len tails
+# ---------------------------------------------------------------------------
+PLANNED_CASES = [
+    # (S, Hkv, window, cap, causal, dtype); Hq = 2
+    (384, 2, 0, 0.0, True, jnp.float32),
+    (640, 1, 400, 0.0, True, jnp.float32),     # GQA + window
+    (600, 2, 0, 30.0, True, jnp.float32),      # softcap, padded to 640
+    (1000, 1, 0, 50.0, True, jnp.float32),     # 512 tiles, padded to 1024
+    (600, 1, 160, 0.0, False, jnp.float32),    # non-causal window, padded
+    (640, 2, 0, 0.0, True, jnp.bfloat16),
+    (1000, 1, 0, 30.0, True, jnp.bfloat16),
+]
+
+
+def _planned_inputs(S, Hkv, dtype):
+    ks = jax.random.split(jax.random.PRNGKey(S + Hkv), 3)
+    return (jax.random.normal(ks[0], (1, S, 2, 64), dtype),
+            jax.random.normal(ks[1], (1, S, Hkv, 64), dtype),
+            jax.random.normal(ks[2], (1, S, Hkv, 64), dtype))
+
+
+def _assert_edge_and_interior(S, Hkv, window, causal, dtype):
+    from repro.kernels.flash_attention import block_sizes, tile_counts
+    bq, bk = block_sizes(S, 64, dtype, 2 // Hkv)
+    S_pad = -(-S // max(bq, bk)) * max(bq, bk)
+    masked, free = tile_counts(S_pad, bq, bk, causal=causal, window=window,
+                               kv_len=S)
+    assert S_pad > bk and masked > 0 and free > 0, (bq, bk, masked, free)
+
+
+@pytest.mark.parametrize("S,Hkv,window,cap,causal,dtype", PLANNED_CASES)
+def test_flash_attention_planned_tiles(S, Hkv, window, cap, causal, dtype):
+    _assert_edge_and_interior(S, Hkv, window, causal, dtype)
+    q, k, v = _planned_inputs(S, Hkv, dtype)
+    out = ops.flash_attention(q, k, v, causal=causal, window=window,
+                              softcap=cap)
+    ref = flash_attention_ref(q, k, v, causal=causal, window=window,
+                              softcap=cap)
+    assert out.dtype == dtype
+    tol = 2e-2 if dtype == jnp.bfloat16 else 2e-4
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref, np.float32), rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("S,Hkv,window,cap,causal,dtype", PLANNED_CASES)
+def test_flash_attention_grads_planned_tiles(S, Hkv, window, cap, causal,
+                                             dtype):
+    q, k, v = _planned_inputs(S, Hkv, dtype)
+    f = lambda q, k, v: jnp.sum(jnp.sin(ops.flash_attention(
+        q, k, v, causal=causal, window=window, softcap=cap
+    ).astype(jnp.float32)))
+    fr = lambda q, k, v: jnp.sum(jnp.sin(flash_attention_ref(
+        q, k, v, causal=causal, window=window, softcap=cap
+    ).astype(jnp.float32)))
+    g = jax.grad(f, argnums=(0, 1, 2))(q, k, v)
+    gr = jax.grad(fr, argnums=(0, 1, 2))(q, k, v)
+    tol = 2e-2 if dtype == jnp.bfloat16 else 1e-5
+    for name, a, b in zip("qkv", g, gr):
+        assert a.dtype == dtype
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(b, np.float32),
+                                   rtol=tol, atol=tol, err_msg=f"d{name}")
+
+
+@pytest.mark.parametrize("S,D,rep,plan,counts", [
+    (512, 64, 1, (512, 512), (1, 0)),       # X_32, s512 cell: one tile
+    (4096, 64, 1, (512, 512), (8, 28)),     # X_32, s4096 cell
+    (4096, 128, 1, (512, 512), (8, 28)),    # head dim 128, MHA
+    (8192, 64, 1, (256, 512), (32, 240)),   # 512 x 512 is refused on v5e
+    (640, 64, 1, (128, 128), (5, 10)),      # larger tiles would pad S
+    (2048, 128, 4, (256, 512), (8, 12)),    # 32 q / 8 kv heads: 4 staged
+    (2048, 256, 2, (256, 512), (8, 12)),    # gemma-2 widths
+    (500, 64, 1, (512, 512), (1, 0)),       # padded to one 512 tile
+    (100, 64, 1, (128, 128), (1, 0)),       # short: one aligned tile
+])
+def test_tile_plan(S, D, rep, plan, counts):
+    """The planned (block_q, block_k) and the (masked, mask-free) tiles a
+    head walks per causal call of the padded sequence; 128-row tiles at
+    S = 4096 walked 32 masked and 496 mask-free."""
+    from repro.kernels.flash_attention import tile_counts, tile_plan
+    assert tile_plan(S, D, jnp.bfloat16, rep) == plan
+    S_pad = -(-S // max(plan)) * max(plan)
+    assert tile_counts(S_pad, *plan, kv_len=S) == counts
+    assert tile_counts(4096, 128, 128) == (32, 496)
+
+
+@pytest.mark.parametrize("given", [(None, None), (32, 32), (128, None),
+                                   (None, 64), (1024, 1024)])
+def test_block_sizes_hold_after_padding(given):
+    """ops pads S to what ``block_sizes`` picks; the kernels, asked again on
+    the padded length, pick the same sizes, and the padding stays under
+    one tile."""
+    import math
+    from repro.kernels.flash_attention import block_sizes
+    for S in range(1, 2100, 7):
+        for D, rep in ((64, 1), (128, 4), (256, 2)):
+            bq, bk = block_sizes(S, D, jnp.bfloat16, rep, *given)
+            mult = bq * bk // math.gcd(bq, bk)
+            S_pad = -(-S // mult) * mult
+            assert S_pad - S < max(bq, bk), (S, D, rep, bq, bk)
+            assert block_sizes(S_pad, D, jnp.bfloat16, rep, *given) \
+                == (bq, bk), (S, D, rep)
+
+
+def _pallas_calls(fn, *args):
+    """(grid, first operand's shape) of every pallas_call in fn's jaxpr."""
+    calls = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                calls.append((tuple(eqn.params["grid_mapping"].grid),
+                              eqn.invars[0].aval.shape))
+            for val in eqn.params.values():
+                for u in (val if isinstance(val, (tuple, list)) else (val,)):
+                    if hasattr(u, "jaxpr") and hasattr(u.jaxpr, "eqns"):
+                        walk(u.jaxpr)
+                    elif hasattr(u, "eqns"):
+                        walk(u)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return calls
+
+
+@pytest.mark.parametrize("S,tile", [(200, 256), (500, 512)])
+def test_flash_attention_pads_to_the_planned_tile(S, tile):
+    """A sequence one planned tile covers is padded up to that tile, not
+    run as a tile of S rows (500 is no multiple of the v5e's 8 sublanes):
+    forward, dq and dkv each walk one tile a head."""
+    q, k, v = _planned_inputs(S, 1, jnp.float32)
+    loss = lambda f: lambda q, k, v: jnp.sum(jnp.sin(f(q, k, v, softcap=30.0)))
+    grad = jax.grad(loss(ops.flash_attention), argnums=(0, 1, 2))
+    calls = _pallas_calls(grad, q, k, v)
+    assert sorted(calls) == [((1, 1, 1), (1, 1, 2, tile, 64))] \
+        + [((1, 2, 1), (1, 2, tile, 64))] * 2, calls
+    g = grad(q, k, v)
+    gr = jax.grad(loss(flash_attention_ref), argnums=(0, 1, 2))(q, k, v)
+    for name, a, b in zip("qkv", g, gr):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5,
+                                   atol=1e-5, err_msg=f"d{name}")
+
+
+def test_flash_bwd_padded_keys_get_no_gradient():
+    """The backward kernels called on a padded sequence: keys past
+    ``kv_len`` are masked in dk/dv as in the forward (non-causal, so only
+    the kv_len tail masks the last k-tile)."""
+    from repro.kernels import flash_attention as fa
+    S, kv_len = 640, 600
+    q, k, v = _planned_inputs(S, 2, jnp.float32)
+    do = jax.random.normal(jax.random.PRNGKey(5), q.shape)
+    out, lse = fa.flash_attention_fwd(q, k, v, causal=False, kv_len=kv_len,
+                                      interpret=True)
+    dq, dk, dv = fa.flash_attention_bwd(q, k, v, out, lse, do, causal=False,
+                                        kv_len=kv_len, interpret=True)
+    assert not np.any(np.asarray(dk[:, kv_len:]))
+    assert not np.any(np.asarray(dv[:, kv_len:]))
+    assert np.all(np.isfinite(np.asarray(dq)))
+
+
 @pytest.mark.parametrize("rows,d", [(16, 128), (37, 256), (4, 512), (256, 128)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_rmsnorm(rows, d, dtype):

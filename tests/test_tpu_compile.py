@@ -80,8 +80,18 @@ CASES = {
     # paper X_32: S = 16x = 512, 16 heads of 64, MHA
     "flash_fwd_x32": _flash_case(1, 512, 16, 16, 64, bwd=False),
     "flash_fwd_bwd_x32": _flash_case(1, 512, 16, 16, 64, bwd=True),
+    # X_32 at the long-context cell's S = 4096: the planned tiles fit VMEM
+    "flash_fwd_bwd_x32_s4096": _flash_case(1, 4096, 16, 16, 64, bwd=True),
     # yi-6b width: S = 4096, 32 q / 4 kv heads of 128 (GQA)
     "flash_fwd_yi6b": _flash_case(1, 4096, 32, 4, 128, bwd=False),
+    # head dim 128 both ways where the q heads are not grouped
+    "flash_fwd_bwd_d128_mha": _flash_case(1, 4096, 8, 8, 128, bwd=True),
+    # GQA, 32 q / 8 kv heads of 128 (mistral-7b): dkv stages 4 q heads
+    "flash_fwd_bwd_gqa_s2048": _flash_case(1, 2048, 32, 8, 128, bwd=True),
+    # S = 8192 at head dim 64: the plan's 256 x 512 (512 x 512 is refused)
+    "flash_fwd_bwd_x32_s8192": _flash_case(1, 8192, 16, 16, 64, bwd=True),
+    # S = 500 is padded to one 512 x 512 tile
+    "flash_fwd_bwd_s500": _flash_case(1, 500, 16, 16, 64, bwd=True),
     "rmsnorm_fwd_d4096": _rms_case(bwd=False),
     "rmsnorm_fwd_bwd_d4096": _rms_case(bwd=True),
     "fused_adamw_1024x4096": _adamw_case,
